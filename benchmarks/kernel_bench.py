@@ -25,6 +25,8 @@ from repro.core.winograd import (WinogradSpec, _pad_amounts, direct_conv2d,
                                  winograd_conv2d)
 from repro.kernels import ref as kref
 from repro.kernels.wino_gemm import wino_gemm
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import require_host_devices
 
 SHAPES = [  # (B, H, W, Cin, Cout) — ResNet18-CIFAR ×0.5 stage shapes
     (8, 32, 32, 32, 32),
@@ -109,7 +111,7 @@ def hbm_model_crosscheck(smoke: bool = False) -> dict:
     T, n = B * nt_h * nt_w, spec.n
     P = n * n
     mats = make_matrices(spec)
-    tiles = jnp.zeros((T, Ci, n, n), jnp.float32)
+    tiles = jnp.zeros((P, T, Ci), jnp.float32)
     scales = jnp.ones((P, 1), jnp.float32)
     xq = jnp.zeros((P, T, Ci), jnp.int8)
     uq = jnp.zeros((P, Ci, Co), jnp.int8)
@@ -121,11 +123,11 @@ def hbm_model_crosscheck(smoke: bool = False) -> dict:
     for name, lowered in (
         ("input_transform",
          input_transform.lower(tiles, cinvt, bpt, scales,
-                               changes_base=True, interpret=True)),
+                               changes_base=True)),
         ("fused_gemm_output",
          fused_gemm_output.lower(xq, uq, scales, scales, cinvt, apt,
                                  m=spec.m, requant_bits=9,
-                                 changes_base=True, interpret=True)),
+                                 changes_base=True)),
     ):
         bb = entry_boundary_bytes(lowered.compile().as_text())
         boundary += bb["total"]
@@ -159,11 +161,14 @@ def main(argv=None):
     ap.add_argument("--json", default="BENCH_kernel.json",
                     help="machine-readable output path")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="split the host CPU into N XLA devices so the "
-                         "sharded rows cover real multi-device meshes")
+                    help="CPU only: split the host CPU into N XLA devices "
+                         "so the sharded rows cover real multi-device "
+                         "meshes")
     args = ap.parse_args(argv)
     ensure_host_devices(args.host_devices, "benchmarks.kernel_bench",
                         argv if argv is not None else sys.argv[1:])
+    require_host_devices(args.host_devices)
+    enable_compile_cache()
 
     hbm_model_crosscheck(smoke=args.smoke)
     if not args.smoke:
@@ -177,8 +182,8 @@ def main(argv=None):
     plan_bench(smoke=args.smoke)
     write_json(args.json, smoke=args.smoke,
                backend=jax.default_backend(),
-               note="interpret-mode Pallas on CPU; TPU numbers from the "
-                    "roofline model")
+               note=f"Pallas on {jax.default_backend()} (interpret mode "
+                    "on cpu); CPU walls are regression guards, not speed")
 
 
 def xla_sweep():
@@ -216,8 +221,8 @@ def gemm_micro():
     xq = jax.random.randint(key, (P, M, K), -127, 128, jnp.int8)
     wq = jax.random.randint(jax.random.PRNGKey(2), (P, K, N), -127, 128,
                             jnp.int8)
-    us = time_fn(lambda a, b: wino_gemm(a, b, blocks=(128, 64, 64),
-                                        interpret=True), xq, wq, iters=3)
+    us = time_fn(lambda a, b: wino_gemm(a, b, blocks=(128, 64, 64)),
+                 xq, wq, iters=3)
     emit(f"pallas_wino_gemm_interp_{P}x{M}x{K}x{N}", us,
          "interpret-mode (CPU emulation)")
     us = time_fn(jax.jit(kref.wino_gemm_ref), xq, wq)
@@ -374,7 +379,7 @@ def autotune_bench(smoke: bool = False):
     for name, spec, (T, Ci, Co) in cases:
         tag = f"{name}_T{T}x{Ci}->{Co}"
         res = autotune_blocks(spec, T, Ci, Co, hadamard_bits=9,
-                              interpret=True, iters=3 if smoke else 5,
+                              iters=3 if smoke else 5,
                               warmup=1, max_candidates=6 if smoke else 10)
         emit(f"autotune_fused_default_{tag}", res.default_us,
              "spec-default blocks", shape=tag,

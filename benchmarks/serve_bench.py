@@ -50,6 +50,7 @@ import jax
 
 from benchmarks.common import emit, time_fn, write_json
 from repro.data.pipeline import cifar_batch_at
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import resnet as RN
 from repro.models.param import init_params
 from repro.serving import (ServeConfig, ServingLoop, run_poisson_load,
@@ -96,6 +97,7 @@ def main(argv=None):
     ap.add_argument("--width", type=float, default=0.25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     buckets = (1, 8) if args.smoke else (1, 2, 4, 8)
     utils = (0.6,) if args.smoke else (0.4, 0.7)

@@ -12,7 +12,7 @@ and its post-mortem:
     in the serving batcher this silently doubled pre-compiled geometry
     warmup (the PR-6 bucket-executor bug). All call sites of one jitted
     function should commit to one flavor. ``shard_map``-wrapped
-    callables (including ``shard_map_compat``) are tracked the same way
+    callables (``jax.shard_map``) are tracked the same way
     — the sharded serving executor is exactly such a callable, and its
     dispatch cache doubles identically.
 
@@ -103,13 +103,11 @@ def _dotted(node: ast.AST) -> str:
 
 _JIT_NAMES = ("jax.jit", "jit", "pjit", "jax.pjit")
 #: Wrappers whose result dispatches like a jitted callable — shard_map
-#: (and this repo's version-compat shim) builds a traced, cached SPMD
-#: program, so mixed numpy/device argument flavors at its call sites
-#: double the dispatch cache exactly like plain jit. Matched on the
-#: trailing name so ``jax.shard_map``, ``jax.experimental.shard_map.
-#: shard_map`` and ``repro.distributed.sharding.shard_map_compat`` all
-#: count.
-_SHARD_MAP_NAMES = ("shard_map", "shard_map_compat")
+#: builds a traced, cached SPMD program, so mixed numpy/device argument
+#: flavors at its call sites double the dispatch cache exactly like
+#: plain jit. Matched on the trailing name, so ``jax.shard_map`` and a
+#: bare imported ``shard_map`` both count.
+_SHARD_MAP_NAMES = ("shard_map",)
 
 
 def _is_jit_expr(node: ast.AST) -> bool:

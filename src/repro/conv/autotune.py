@@ -123,11 +123,11 @@ def candidate_blocks(P: int, m: int, T: int, cin: int, cout: int,
 
 
 def _time_fused(xq, u_q, deq, rq, mats, spec, hadamard_bits, blocks,
-                interpret, iters: int, warmup: int) -> float:
+                iters: int, warmup: int) -> float:
     fn = lambda: fused_gemm_output(
         xq, u_q, deq, rq, mats.CinvT, mats.APT, m=spec.m,
         requant_bits=hadamard_bits, changes_base=spec.changes_base,
-        blocks=blocks, interpret=interpret)
+        blocks=blocks)
     for _ in range(warmup):
         jax.block_until_ready(fn())
     times = []
@@ -139,7 +139,7 @@ def _time_fused(xq, u_q, deq, rq, mats, spec, hadamard_bits, blocks,
     return times[len(times) // 2] * 1e6
 
 
-#: In-process memo: (spec, T, cin, cout, hadamard_bits, interpret) →
+#: In-process memo: (spec, T, cin, cout, hadamard_bits, options) →
 #: TuneResult. Layers sharing a tile geometry tune once.
 _CACHE: dict = {}
 
@@ -150,7 +150,6 @@ def clear_cache():
 
 def autotune_blocks(spec: WinogradSpec, T: int, cin: int, cout: int, *,
                     hadamard_bits: Optional[int] = None,
-                    interpret: bool = True,
                     iters: int = 3, warmup: int = 1,
                     max_candidates: int = 12,
                     budget_bytes: int = VMEM_BUDGET_BYTES) -> TuneResult:
@@ -165,12 +164,12 @@ def autotune_blocks(spec: WinogradSpec, T: int, cin: int, cout: int, *,
     search, keeping the biggest-block (fewest-grid-steps) candidates,
     which always include the clamped spec default.
 
-    Cached per (spec, shape, bits, interpret, search options)
+    Cached per (spec, shape, bits, search options)
     in-process; the durable cache is the packed state
     (``PackedWinogradWeights.blocks``). The search options are part of
     the key so a capped quick search never masquerades as a wider one.
     """
-    key = (spec, T, cin, cout, hadamard_bits, interpret,
+    key = (spec, T, cin, cout, hadamard_bits,
            iters, warmup, max_candidates, budget_bytes)
     hit = _CACHE.get(key)
     if hit is not None:
@@ -198,7 +197,7 @@ def autotune_blocks(spec: WinogradSpec, T: int, cin: int, cout: int, *,
     for c in cands:
         validate_blocks(c)
         us = _time_fused(xq, u_q, deq, rq, mats, spec, hadamard_bits, c,
-                         interpret, iters, warmup)
+                         iters, warmup)
         timings.append((c, us))
     timings.sort(key=lambda t: t[1])
     default_us = next(us for c, us in timings if c == d_clamped)

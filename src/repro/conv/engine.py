@@ -144,7 +144,6 @@ class ConvEngine:
                  padding: str = "same",
                  hadamard_bits: "Optional[int] | str" = "from_spec",
                  fused: bool = True,
-                 interpret: bool = True,
                  mesh=None,
                  data_axis="data",
                  model_axis=None,
@@ -244,7 +243,6 @@ class ConvEngine:
         self.padding = padding
         self.hadamard_bits = hadamard_bits
         self.fused = fused
-        self.interpret = interpret
         self.mesh = mesh
         self.data_axis = data_axis
         self.model_axis = model_axis
@@ -433,7 +431,6 @@ class ConvEngine:
                     tiles, pk.u_q, pk.w_scales, pk.in_scales,
                     pk.hadamard_amax, spec=spec, geom=geom,
                     mesh=self.mesh, hadamard_bits=hbits,
-                    interpret=self.interpret,
                     blocks=self._layer_blocks(pk),
                     data_axis=self.data_axis,
                     model_axis=self.model_axis)
@@ -443,11 +440,10 @@ class ConvEngine:
                 u_q=pk.u_q, w_scales=pk.w_scales,
                 hadamard_bits=hbits,
                 h_amax=pk.hadamard_amax if pk.calibrated else None,
-                fused=self.fused, blocks=self._layer_blocks(pk),
-                interpret=self.interpret)
+                fused=self.fused, blocks=self._layer_blocks(pk))
         return winograd_conv2d_int8(
             x, w, spec, pad, hadamard_bits=hbits,
-            fused=self.fused, blocks=self.blocks, interpret=self.interpret)
+            fused=self.fused, blocks=self.blocks)
 
     def _calibrate_conv(self, x, w, pk, layer, pad, spec, hbits):
         """One int8 conv under calibration: extract tiles once, record
@@ -465,18 +461,17 @@ class ConvEngine:
         self._calib_uq[layer] = (u_q, w_scales)
         # Calibration fixes the serving tile geometry — the shape key
         # the block autotuner searches at end_calibration.
-        self._tile_geom[layer] = (int(tiles.shape[0]),
+        self._tile_geom[layer] = (int(tiles.shape[1]),
                                   int(u_q.shape[1]), int(u_q.shape[2]))
         blocks = self._layer_blocks(pk)
         scales = scales_from_abs_max(amax)
         if hbits is None:
             return execute_int8(tiles, u_q, w_scales, scales, spec=spec,
                                 geom=geom, hadamard_bits=None,
-                                blocks=blocks, interpret=self.interpret)
+                                blocks=blocks)
         y, amax_h = execute_int8(tiles, u_q, w_scales, scales, spec=spec,
                                  geom=geom, hadamard_bits=hbits,
-                                 blocks=blocks, interpret=self.interpret,
-                                 with_stats=True)
+                                 blocks=blocks, with_stats=True)
         self._amax_h[layer] = merge_abs_max(self._amax_h.get(layer), amax_h)
         return y
 
@@ -660,7 +655,6 @@ class ConvEngine:
                 continue
             res = autotune_blocks(self._layer_spec(layer), *geom,
                                   hadamard_bits=self._layer_hbits(layer),
-                                  interpret=self.interpret,
                                   **self.autotune_opts)
             tuned[layer] = res.blocks
             self.packed[layer] = dataclasses.replace(

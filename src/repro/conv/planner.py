@@ -47,7 +47,7 @@ entry that contradicts the certifier raises at pack time — the planner
 pre-filters candidates, so a contradicting plan is corrupted state, not
 a tunable.
 
-On this container's interpret-mode CPU backend the measured plan
+On the CPU backend, where the kernels run in interpret mode, the measured plan
 typically routes *everything* direct (emulated Pallas kernels lose to
 XLA's native conv at every shape — see BENCH_kernel.json); that is the
 correct answer for this backend, and the crossover the plan exists to
@@ -328,7 +328,7 @@ class CandidateCost:
     rel_err: float
 
 
-#: (geom.key(), entry, interpret, iters, warmup) → CandidateCost.
+#: (geom.key(), entry, iters, warmup, padding) → CandidateCost.
 #: Search options are part of the key so a quick 1-iter plan never
 #: masquerades as a carefully-timed one (same contract as
 #: ``repro.conv.autotune._CACHE``).
@@ -372,7 +372,7 @@ def _direct_fn(stride: int, padding: str):
 
 def measure_layer(geom: LayerGeom,
                   candidates: Optional[Sequence[PlanEntry]] = None, *,
-                  interpret: bool = True, iters: int = 3, warmup: int = 1,
+                  iters: int = 3, warmup: int = 1,
                   padding: str = "same") -> tuple[CandidateCost, ...]:
     """Time every candidate of one layer geometry on its serving path.
 
@@ -394,7 +394,7 @@ def measure_layer(geom: LayerGeom,
     y_ref = None
     out = []
     for entry in candidates:
-        key = (geom.key(), entry, interpret, iters, warmup, padding)
+        key = (geom.key(), entry, iters, warmup, padding)
         hit = _MEASURE_CACHE.get(key)
         if hit is not None:
             out.append(hit)
@@ -411,7 +411,7 @@ def measure_layer(geom: LayerGeom,
             eng = ConvEngine(entry.spec(),
                              ConvPolicy(backend="winograd_int8"),
                              hadamard_bits=entry.hadamard_bits,
-                             interpret=interpret, certify="off")
+                             certify="off")
             eng.prepare([(geom.layer, w, geom.stride)])
             with eng.calibration():
                 eng.conv2d(x, w, layer=geom.layer, stride=geom.stride)
@@ -544,7 +544,7 @@ def build_plan(geoms: Iterable[LayerGeom], *,
                hadamard_bits: Sequence[Optional[int]]
                = DEFAULT_HADAMARD_BITS,
                certify: bool = True,
-               interpret: bool = True, iters: int = 3, warmup: int = 1,
+               iters: int = 3, warmup: int = 1,
                err_slack: float = 0.02,
                err_budget: Optional[float] = None,
                ) -> tuple[Plan, dict[str, tuple[CandidateCost, ...]]]:
@@ -563,7 +563,7 @@ def build_plan(geoms: Iterable[LayerGeom], *,
                                   tile_sizes=tile_sizes, bases=bases,
                                   hadamard_bits=hadamard_bits,
                                   certify=certify)
-        costs[geom.layer] = measure_layer(geom, cands, interpret=interpret,
-                                          iters=iters, warmup=warmup)
+        costs[geom.layer] = measure_layer(geom, cands, iters=iters,
+                                          warmup=warmup)
     return solve_plan(costs, baseline=baseline, err_slack=err_slack,
                       err_budget=err_budget), costs

@@ -154,10 +154,13 @@ def flex_init(spec: WinogradSpec, points=None) -> dict[str, jnp.ndarray]:
 
 def _sandwich(M: jnp.ndarray, X: jnp.ndarray, N: Optional[jnp.ndarray] = None
               ) -> jnp.ndarray:
-    """M @ X @ Nᵀ over the trailing two dims of X (N defaults to M)."""
+    """M @ X @ Nᵀ over the trailing two dims of X (N defaults to M), at
+    full fp32 precision on every backend (a TPU's default would round
+    the operands to bf16)."""
     if N is None:
         N = M
-    return jnp.einsum("ij,...jk,lk->...il", M, X, N)
+    return jnp.einsum("ij,...jk,lk->...il", M, X, N,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _q(x: jnp.ndarray, bits: Optional[int], axis=None) -> jnp.ndarray:

@@ -23,8 +23,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["rules", "pspec", "named_sharding", "tree_shardings",
-           "batch_pspec", "constrain", "shard_map_compat",
-           "axis_extent", "data_axis_extent"]
+           "batch_pspec", "constrain", "axis_extent", "data_axis_extent"]
 
 
 def rules(fsdp: bool = False, multi_pod: bool = True,
@@ -188,24 +187,3 @@ def data_axis_extent(mesh: Mesh, axis="data") -> int:
     the general form it raises on an axis the mesh does not have."""
     return _axis_extent(mesh, axis)
 
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the 0.4/0.5+ API split.
-
-    Newer jax promotes shard_map out of experimental and eventually
-    renames the replication-check knob check_rep → check_vma; 0.4.x
-    keeps it under ``jax.experimental.shard_map``. The knob is gated on
-    the actual signature (some versions have top-level ``jax.shard_map``
-    but still the old kwarg). Either way the check is disabled — callers
-    here return per-shard outputs whose replication the checker cannot
-    infer through Pallas calls.
-    """
-    if hasattr(jax, "shard_map"):           # jax >= 0.5
-        import inspect
-        params = inspect.signature(jax.shard_map).parameters
-        knob = "check_vma" if "check_vma" in params else "check_rep"
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{knob: False})
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)

@@ -15,19 +15,21 @@ transform into ONE ``pallas_call``:
                  8/9-bit grid with the calibrated scale rq[p], dequant back
                  (all in-register), then the output-transform sandwich
                  C⁻ᵀ(·)C⁻¹ → A_Cᵀ(·)A_C over the n×n tile window
-                 → write the (bm, bn, m, m) fp32 output block.
+                 → write the (m², bm, bn) fp32 output block.
 
-HBM traffic per call: read Xq + u_q once, write the (T, Cout, m, m)
+HBM traffic per call: read Xq + u_q once, write the (m², T, Cout)
 output once.  Zero fp32 intermediates in HBM.
 
 The per-position accumulator lives in a VMEM scratch buffer that persists
 across the sequential K grid steps (the canonical Pallas revisiting
 schedule, same as ``wino_gemm`` — just with the P axis folded into the
-block so the epilogue sees every position of an (i, j) tile).
+block so the epilogue sees every position of an (i, j) tile). Scales and
+transform matrices are SMEM scalars; every epilogue term is a scalar
+times one (bm, bn) plane.
 
 Exactness: the requant math is ``requant_plane`` (shared with the
 ``wino_gemm`` epilogue) and the transform sandwich is
-``_sandwich_unrolled`` (shared with ``wino_transform._output_kernel``),
+``wino_transform.output_planes`` (shared with the staged output kernel),
 applied in the same order with the same fp32 operands as the staged
 path.  The integer pipeline — GEMM accumulation and the Hadamard-domain
 requantized values — is therefore *exactly* equal to staged
@@ -50,9 +52,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import qmax
-from repro.kernels.wino_gemm import (_pad_to, default_blocks,
-                                     requant_plane, validate_blocks)
-from repro.kernels.wino_transform import sandwich_stack
+from repro.kernels import backend
+from repro.kernels.backend import smem_spec
+from repro.kernels.wino_gemm import (INT8_DOT_PRECISION, _pad_to,
+                                     default_blocks, requant_plane,
+                                     validate_blocks)
+from repro.kernels.wino_transform import output_planes, scalars
 
 __all__ = ["fused_gemm_output"]
 
@@ -79,47 +84,43 @@ def _fused_kernel(x_ref, w_ref, deq_ref, rq_ref, cinvt_ref, apt_ref,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=INT8_DOT_PRECISION,
         preferred_element_type=jnp.int32,
     )
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _epilogue():
-        cinvt = cinvt_ref[...]
-        apt = apt_ref[...]
-        cols = []
+        planes = []
+        deq, rq = scalars(deq_ref, n * n), scalars(rq_ref, n * n)
         for p in range(n * n):
             if qm is None:
                 # No Hadamard stage: plain dequant (= staged
                 # output_transform with deq scales).
-                cols.append(acc_ref[p, ...].astype(jnp.float32)
-                            * deq_ref[p, 0])
+                planes.append(acc_ref[p].astype(jnp.float32) * deq[p])
             else:
-                q = requant_plane(acc_ref[p, ...], deq_ref[p, 0],
-                                  rq_ref[p, 0], qm)
-                cols.append(q * rq_ref[p, 0])
-        h = jnp.stack(cols, -1).reshape(*cols[0].shape, n, n)
-        if changes_base:
-            h = sandwich_stack(cinvt, cinvt, h, n, n)
-        out_ref[...] = sandwich_stack(apt, apt, h, n, m)
+                q = requant_plane(acc_ref[p], deq[p], rq[p], qm)
+                planes.append(q * rq[p])
+        for p, y in enumerate(output_planes(planes, cinvt_ref, apt_ref, n, m,
+                                            changes_base)):
+            out_ref[p] = y
 
 
 @functools.partial(jax.jit, static_argnames=("m", "requant_bits",
-                                             "changes_base", "blocks",
-                                             "interpret"))
+                                             "changes_base", "blocks"))
 def fused_gemm_output(xq: jnp.ndarray, u_q: jnp.ndarray, deq: jnp.ndarray,
                       rq: jnp.ndarray, cinvt: jnp.ndarray,
                       apt: jnp.ndarray, *, m: int,
                       requant_bits: int | None = None,
                       changes_base: bool = True,
-                      blocks: tuple[int, int, int] | None = None,
-                      interpret: bool = False) -> jnp.ndarray:
+                      blocks: tuple[int, int, int] | None = None
+                      ) -> jnp.ndarray:
     """Fused GEMM → Hadamard requant → output transform.
 
     xq: (P, T, Cin) int8 (from ``input_transform``), u_q: (P, Cin, Cout)
     int8 prepared weights, deq/rq: (P, 1) fp32 per-position dequant /
     requant scales (``rq`` ignored when ``requant_bits`` is None — pass
     ones), cinvt (n, n) / apt (m, n) transform operands
-    → (T, Cout, m, m) fp32 spatial output tiles.
+    → (m², T, Cout) fp32 spatial output tiles, position-major.
 
     ``blocks`` (bm, bn, bk) overrides ``wino_gemm.default_blocks(P)`` —
     the per-shape tuning knob, reachable from ``ops.execute_int8``,
@@ -156,14 +157,12 @@ def fused_gemm_output(xq: jnp.ndarray, u_q: jnp.ndarray, deq: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((P, bm, bk), lambda i, j, k: (0, i, k)),
             pl.BlockSpec((P, bk, bn), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((P, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((P, 1), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((n, n), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((m, n), lambda i, j, k: (0, 0)),
+            smem_spec(), smem_spec(), smem_spec(), smem_spec(),
         ],
-        out_specs=pl.BlockSpec((bm, bn, m, m), lambda i, j, k: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Tp, Np, m, m), jnp.float32),
+        out_specs=pl.BlockSpec((m * m, bm, bn), lambda i, j, k: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((m * m, Tp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, bm, bn), jnp.int32)],
-        interpret=interpret,
-    )(xp, wp, deq, rq, cinvt, apt)
-    return out[:T, :N]
+        interpret=backend.interpret_mode(),
+    )(xp, wp, deq.reshape(-1), rq.reshape(-1), cinvt.reshape(-1),
+      apt.reshape(-1))
+    return out[:, :T, :N]

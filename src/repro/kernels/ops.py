@@ -2,14 +2,18 @@
 convolution (the inference path; QAT uses the fake-quant path in core/).
 
 Staged pipeline (NHWC):
-    extract tiles (XLA gather)                    → (T, Cin, n, n) fp
+    extract tiles (XLA gather)                    → (n², T, Cin) fp
     kernels.input_transform   (fused, 1 HBM pass) → (n², T, Cin) int8
     kernels.wino_gemm         (MXU int8 GEMMs)    → (n², T, Cout) int32
     [optional Hadamard requant to 8/9 bits — the paper's knob; with
      calibrated statistics it runs as wino_gemm's in-register epilogue,
      dynamic derivation stays XLA glue]
-    kernels.output_transform  (fused, 1 HBM pass) → (T, Cout, m, m) fp
+    kernels.output_transform  (fused, 1 HBM pass) → (m², T, Cout) fp
     reassemble                                    → (N, Ho, Wo, Cout)
+
+Every tensor between extraction and reassembly is position-major, with
+(T, C) as its two minor axes: the layout the Mosaic-compiled kernels
+block over (see ``kernels.wino_transform``).
 
 Fused serving pipeline (``fused=True``, requires calibrated Hadamard
 statistics when the 8/9-bit stage is on):
@@ -41,7 +45,7 @@ over a 2-D (data × model) mesh — the tile axis T of the quantized
 ``Xq`` shard_maps across the data axis, the per-position GEMM's N axis
 (Cout) shards across the model axis with each device holding only its
 (P, Cin, Cout/D_model) weight shard, and one per-layer ``all_gather``
-of the small (T_local, Cout_local, m, m) spatial outputs reassembles
+of the small (m², T_local, Cout_local) spatial outputs reassembles
 the channels. Bit-identical to single-device fused execution on any
 mesh shape; dynamic-requant layers run sharded too (shard-local
 ``|·|max`` + one ``lax.pmax`` over the plane — exact).
@@ -66,6 +70,7 @@ from repro.core.quantization import QuantConfig, qmax
 from repro.core.winograd import (WinogradMatrices, WinogradSpec,
                                  _extract_tiles_1d_axis, _pad_amounts,
                                  make_matrices, transform_weights_2d)
+from repro.kernels import backend
 from repro.kernels import ref as kref
 from repro.kernels.fused_serve import fused_gemm_output
 from repro.kernels.q8_matmul import q8_matmul
@@ -86,22 +91,23 @@ def _geometry(x_shape, m: int, r: int, padding: str):
 
 @functools.partial(jax.jit, static_argnames=("m", "r", "n", "padding"))
 def _extract(x: jnp.ndarray, m: int, r: int, n: int, padding: str):
-    """(N,H,W,C) → (T, C, n, n) overlapping tiles, one fused call."""
+    """(N,H,W,C) → (n², T, C) overlapping tiles, one fused call."""
     N, H, W, C = x.shape
     lo_h, hi_h, nt_h, _ = _pad_amounts(H, m, r, padding)
     lo_w, hi_w, nt_w, _ = _pad_amounts(W, m, r, padding)
     xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
     t = _extract_tiles_1d_axis(xp, xp.shape[1], m, n, nt_h, axis=1)
     t = _extract_tiles_1d_axis(t, t.shape[3], m, n, nt_w, axis=3)
-    t = jnp.transpose(t, (0, 1, 3, 5, 2, 4))        # (N,th,tw,C,n,n)
+    t = jnp.transpose(t, (2, 4, 0, 1, 3, 5))        # (n,n,N,th,tw,C)
     T = N * nt_h * nt_w
-    return t.reshape(T, C, n, n)
+    return t.reshape(n * n, T, C)
 
 
 def _reassemble(y: jnp.ndarray, geom, m: int) -> jnp.ndarray:
+    """(m², T, C) output tiles → (N, Ho, Wo, C)."""
     N, nt_h, nt_w, Ho, Wo = geom
-    y = y.reshape(N, nt_h, nt_w, -1, m, m)
-    y = jnp.transpose(y, (0, 1, 4, 2, 5, 3))
+    y = y.reshape(m, m, N, nt_h, nt_w, -1)
+    y = jnp.transpose(y, (2, 3, 0, 4, 1, 5))        # (N,th,m,tw,m,C)
     y = y.reshape(N, nt_h * m, nt_w * m, -1)
     return y[:, :Ho, :Wo, :]
 
@@ -144,7 +150,7 @@ def prepare_weights_int8(w: jnp.ndarray, spec: WinogradSpec
 
 @functools.partial(jax.jit, static_argnames=("spec",))
 def _tiles_abs_max(tiles: jnp.ndarray, spec: WinogradSpec) -> jnp.ndarray:
-    """Per-position abs-max of extracted (T,Cin,n,n) tiles in the
+    """Per-position abs-max of extracted (n²,T,Cin) tiles in the
     Winograd input domain → (n²,) fp32.
 
     The dynamic-scale fallback and offline calibration both call exactly
@@ -184,8 +190,7 @@ def winograd_conv2d_int8(x: jnp.ndarray, w: Optional[jnp.ndarray],
                          hadamard_bits: Optional[int] = None,
                          h_amax: Optional[jnp.ndarray] = None,
                          fused: bool = False,
-                         blocks: Optional[tuple] = None,
-                         interpret: bool = True) -> jnp.ndarray:
+                         blocks: Optional[tuple] = None) -> jnp.ndarray:
     """True-int8 Winograd conv via the Pallas kernels.
 
     Two modes, chosen per argument:
@@ -218,8 +223,8 @@ def winograd_conv2d_int8(x: jnp.ndarray, w: Optional[jnp.ndarray],
     block-independent. See ``repro.conv.autotune`` for the offline
     per-(spec, shape) search.
 
-    ``interpret=True`` (default here) runs the kernel bodies on CPU; on a
-    real TPU deployment pass ``interpret=False``.
+    The kernels compile through Mosaic on a TPU and run in interpret mode
+    on the CPU backend (``kernels.backend.interpret_mode``).
     """
     if u_q is None:
         if w is None:
@@ -234,11 +239,11 @@ def winograd_conv2d_int8(x: jnp.ndarray, w: Optional[jnp.ndarray],
         in_scales = scales_from_abs_max(_tiles_abs_max(tiles, spec))
     return execute_int8(tiles, u_q, w_scales, in_scales, h_amax,
                         spec=spec, geom=geom, hadamard_bits=hadamard_bits,
-                        fused=fused, blocks=blocks, interpret=interpret)
+                        fused=fused, blocks=blocks)
 
 
 def quantize_input(tiles: jnp.ndarray, in_scales: jnp.ndarray, *,
-                   spec: WinogradSpec, interpret: bool) -> jnp.ndarray:
+                   spec: WinogradSpec) -> jnp.ndarray:
     """THE int8 input transform + quantization compile unit.
 
     Every serving mode — staged/fused ``execute_int8``, the standalone
@@ -253,8 +258,7 @@ def quantize_input(tiles: jnp.ndarray, in_scales: jnp.ndarray, *,
     """
     mats = make_matrices(spec)
     return input_transform(tiles, mats.CinvT, mats.BPT, in_scales,
-                           changes_base=spec.changes_base,
-                           interpret=interpret)
+                           changes_base=spec.changes_base)
 
 
 def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
@@ -262,7 +266,7 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
                  h_amax: Optional[jnp.ndarray] = None, *,
                  spec: WinogradSpec, geom: tuple,
                  hadamard_bits: Optional[int],
-                 interpret: bool, with_stats: bool = False,
+                 with_stats: bool = False,
                  fused: bool = False,
                  blocks: Optional[tuple] = None):
     """The serving hot path: consumes extracted tiles, prepared weights
@@ -305,7 +309,7 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
     mats = make_matrices(spec)
     m = spec.m
 
-    Xq = quantize_input(tiles, in_scales, spec=spec, interpret=interpret)
+    Xq = quantize_input(tiles, in_scales, spec=spec)
     deq = in_scales * w_scales                       # (P, 1)
 
     use_fused = (fused and not with_stats
@@ -320,7 +324,7 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
         y = fused_gemm_output(Xq, u_q, deq, rq, mats.CinvT, mats.APT,
                               m=m, requant_bits=hadamard_bits,
                               changes_base=spec.changes_base,
-                              blocks=blocks, interpret=interpret)
+                              blocks=blocks)
         return _reassemble(y, geom, m)
 
     amax_h = None
@@ -331,12 +335,11 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
         # the grid the XLA formula below produces (asserted in tests),
         # minus two HBM passes over the (P, T, Cout) plane.
         rq = _hadamard_rq(h_amax, hadamard_bits)
-        H = wino_gemm(Xq, u_q, blocks=blocks, interpret=interpret,
+        H = wino_gemm(Xq, u_q, blocks=blocks,
                       requant_bits=hadamard_bits, deq=deq, rq=rq)
         deq = rq
     else:
-        H = wino_gemm(Xq, u_q, blocks=blocks,
-                      interpret=interpret)           # (P, T, Cout) int32
+        H = wino_gemm(Xq, u_q, blocks=blocks)       # (P, T, Cout) int32
         if hadamard_bits is not None:
             # The paper's 8/9-bit Hadamard stage: requantize the int32
             # products onto a 2^b-level grid (per position) before the
@@ -352,7 +355,7 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
             deq = s_h[:, :, 0]
 
     y = output_transform(H, deq, mats.CinvT, mats.APT, m=m,
-                         changes_base=spec.changes_base, interpret=interpret)
+                         changes_base=spec.changes_base)
     out = _reassemble(y, geom, m)
     if with_stats:
         return out, amax_h[:, 0, 0]
@@ -364,7 +367,6 @@ def execute_int8_sharded(tiles: jnp.ndarray, u_q: jnp.ndarray,
                          h_amax: Optional[jnp.ndarray] = None, *,
                          spec: WinogradSpec, geom: tuple, mesh,
                          hadamard_bits: Optional[int],
-                         interpret: bool = True,
                          blocks: Optional[tuple] = None,
                          data_axis="data",
                          model_axis=None) -> jnp.ndarray:
@@ -383,14 +385,16 @@ def execute_int8_sharded(tiles: jnp.ndarray, u_q: jnp.ndarray,
     packed bytes per device scale as 1/D_model, which is what lets one
     hot layer outgrow a single device. Exactly ONE model-axis
     collective runs per layer: an ``all_gather`` of the small
-    ``(T_local, Cout_local, m, m)`` spatial outputs; the (P, T, Cout)
+    ``(m², T_local, Cout_local)`` spatial outputs; the (P, T, Cout)
     Hadamard plane never crosses the interconnect. ``model_axis=None``
     (default) is the degenerate D_model = 1 mesh — the PR-3 data-only
     path, bit for bit.
 
-    Numerics: the input quantization runs ONCE on the full tile tensor
+    Numerics: the input quantization runs on the full tile tensor
     through ``quantize_input`` — the same compile unit every other mode
-    dispatches — and only the resulting int8 ``Xq`` is sharded (slicing
+    dispatches, replicated on every device of the mesh because a Mosaic
+    kernel is never partitioned automatically — and only the resulting
+    int8 ``Xq`` is sharded (slicing
     integer data is exact), so "one Xq everywhere" holds by
     construction. Per-element arithmetic downstream is untouched (same
     fused kernel, same operand order, the K grid is not split — "cin"
@@ -437,8 +441,13 @@ def execute_int8_sharded(tiles: jnp.ndarray, u_q: jnp.ndarray,
         rq = _hadamard_rq(h_amax, hadamard_bits)
 
     # One Xq: quantize the FULL tile tensor in the shared compile unit,
-    # then shard the int8 result across the mesh.
-    Xq = quantize_input(tiles, in_scales, spec=spec, interpret=interpret)
+    # then shard the int8 result across the mesh. Mosaic kernels are
+    # never partitioned automatically, so on a TPU the unit runs inside
+    # a replicated shard_map; interpret mode has no such limit.
+    if backend.interpret_mode():
+        Xq = quantize_input(tiles, in_scales, spec=spec)
+    else:
+        Xq = _replicated_quantizer(spec, mesh)(tiles, in_scales)
 
     ndev = axis_extent(mesh, data_axis)
     T = Xq.shape[1]
@@ -447,15 +456,26 @@ def execute_int8_sharded(tiles: jnp.ndarray, u_q: jnp.ndarray,
         Xq = jnp.pad(Xq, ((0, 0), (0, pad), (0, 0)))
 
     da = tuple(data_axis) if isinstance(data_axis, list) else data_axis
-    fn = _sharded_executor(spec, mesh, hadamard_bits, interpret, blocks,
-                           da, model_axis, dynamic)
+    fn = _sharded_executor(spec, mesh, hadamard_bits, blocks, da,
+                           model_axis, dynamic)
     y = fn(Xq, u_q, deq) if dynamic else fn(Xq, u_q, deq, rq)
-    return _reassemble(y[:T], geom, spec.m)
+    return _reassemble(y[:, :T], geom, spec.m)
+
+
+@functools.lru_cache(maxsize=None)
+def _replicated_quantizer(spec: WinogradSpec, mesh: jax.sharding.Mesh):
+    """``quantize_input`` run whole on every device of ``mesh``: each
+    device quantizes the full tile tensor with the single-device
+    program, so the replicated Xq is the single-device Xq."""
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(functools.partial(quantize_input, spec=spec),
+                         mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                         check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
-                      hadamard_bits: Optional[int], interpret: bool,
+                      hadamard_bits: Optional[int],
                       blocks: Optional[tuple], data_axis: str | tuple,
                       model_axis: Optional[str], dynamic: bool):
     """shard_map slab executor, cached per static configuration.
@@ -476,12 +496,11 @@ def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
     ``u_q`` (P, Cin, Cout) shards Cout over ``model_axis`` (matching
     its ``place_packed_state`` placement, so the weights are already
     local); the per-position scale vectors are replicated. Each slab
-    produces (T_local, Cout_local, m, m) and the one per-layer
+    produces (m², T_local, Cout_local) and the one per-layer
     model-axis ``all_gather`` (tiled, in mesh-index order — the same
     order the weight shards were sliced in) reassembles the full Cout
     before the data-axis outputs concatenate via ``out_specs``.
     """
-    from repro.distributed.sharding import shard_map_compat
     from jax.sharding import PartitionSpec as P
     mats = make_matrices(spec)
     qm = qmax(hadamard_bits) if hadamard_bits is not None else None
@@ -496,9 +515,9 @@ def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
         if model_axis is None:
             return y_l
         # THE one model-axis collective of the calibrated hot path:
-        # (T_local, Cout_local, m, m) → (T_local, Cout, m, m), tiled
-        # concat along the channel axis.
-        return jax.lax.all_gather(y_l, model_axis, axis=1, tiled=True)
+        # (m², T_local, Cout_local) → (m², T_local, Cout), tiled concat
+        # along the channel axis.
+        return jax.lax.all_gather(y_l, model_axis, axis=2, tiled=True)
 
     def _slab(xq_l, uq_l, deq, rq):
         # Consumes a pre-quantized (P, T_local, Cin) int8 slab — the
@@ -508,8 +527,7 @@ def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
         return _gather(fused_gemm_output(
             xq_l, uq_l, deq, rq, mats.CinvT, mats.APT,
             m=spec.m, requant_bits=hadamard_bits,
-            changes_base=spec.changes_base,
-            blocks=blocks, interpret=interpret))
+            changes_base=spec.changes_base, blocks=blocks))
 
     def _slab_dynamic(xq_l, uq_l, deq):
         # Sharded dynamic requant: the staged pipeline per slab, with
@@ -518,7 +536,7 @@ def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
         # ``execute_int8`` dynamic branch — max-of-maxima is exact, so
         # every downstream elementwise value matches the single-device
         # derivation bit for bit.
-        H = wino_gemm(xq_l, uq_l, blocks=blocks, interpret=interpret)
+        H = wino_gemm(xq_l, uq_l, blocks=blocks)
         hf = H.astype(jnp.float32) * deq[:, :, None]
         amax = jnp.max(jnp.abs(hf), axis=(1, 2), keepdims=True)
         amax = jax.lax.pmax(amax, red_axes)
@@ -526,22 +544,22 @@ def _sharded_executor(spec: WinogradSpec, mesh: jax.sharding.Mesh,
         Hq = jnp.clip(jnp.round(hf / s_h), -qm, qm).astype(jnp.int32)
         return _gather(output_transform(
             Hq, s_h[:, :, 0], mats.CinvT, mats.APT, m=spec.m,
-            changes_base=spec.changes_base, interpret=interpret))
+            changes_base=spec.changes_base))
 
     xq_spec = P(None, data_axis)        # Xq is (P, T, Cin): shard T
     wq_spec = P(None, None, model_axis)  # u_q (P, Cin, Cout): shard Cout
-    out = P(data_axis)
+    out = P(None, data_axis)             # (m², T, Cout): T is sharded
     if dynamic:
-        return shard_map_compat(_slab_dynamic, mesh,
-                                in_specs=(xq_spec, wq_spec, P()),
-                                out_specs=out)
-    return shard_map_compat(_slab, mesh,
-                            in_specs=(xq_spec, wq_spec, P(), P()),
-                            out_specs=out)
+        return jax.shard_map(_slab_dynamic, mesh=mesh,
+                             in_specs=(xq_spec, wq_spec, P()),
+                             out_specs=out, check_vma=False)
+    return jax.shard_map(_slab, mesh=mesh,
+                         in_specs=(xq_spec, wq_spec, P(), P()),
+                         out_specs=out, check_vma=False)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))
-def q8_linear(x: jnp.ndarray, w: jnp.ndarray, interpret: bool = True,
+@functools.partial(jax.jit, static_argnames=("out_dtype",))
+def q8_linear(x: jnp.ndarray, w: jnp.ndarray,
               out_dtype=jnp.float32) -> jnp.ndarray:
     """Dynamic w8a8 linear: quantize x per-tensor / w per-col, MXU int8 GEMM.
 
@@ -554,5 +572,5 @@ def q8_linear(x: jnp.ndarray, w: jnp.ndarray, interpret: bool = True,
     s_w = jnp.maximum(jnp.max(jnp.abs(w), axis=0), 1e-12) / 127.0
     xq = jnp.clip(jnp.round(x2 / s_x), -127, 127).astype(jnp.int8)
     wq = jnp.clip(jnp.round(w / s_w[None, :]), -127, 127).astype(jnp.int8)
-    y = q8_matmul(xq, wq, s_x, s_w, out_dtype=out_dtype, interpret=interpret)
+    y = q8_matmul(xq, wq, s_x, s_w, out_dtype=out_dtype)
     return y.reshape(*lead, -1)

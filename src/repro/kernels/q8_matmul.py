@@ -18,6 +18,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+from repro.kernels.wino_gemm import INT8_DOT_PRECISION
+
 __all__ = ["q8_matmul"]
 
 DEFAULT_BLOCKS = (128, 128, 512)
@@ -31,6 +34,7 @@ def _q8_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *, k_steps: int):
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=INT8_DOT_PRECISION,
         preferred_element_type=jnp.int32,
     )
 
@@ -51,11 +55,10 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, cfg)
 
 
-@functools.partial(jax.jit, static_argnames=("blocks", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("blocks", "out_dtype"))
 def q8_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, s_x: jnp.ndarray,
               s_w: jnp.ndarray, blocks: tuple[int, int, int] | None = None,
-              out_dtype=jnp.float32, interpret: bool = False) -> jnp.ndarray:
+              out_dtype=jnp.float32) -> jnp.ndarray:
     """x_q (M,K) int8 · w_q (K,N) int8, s_x scalar, s_w (N,) → (M,N) fp.
 
     Zero padding is exact in integer arithmetic; output is cropped.
@@ -85,6 +88,6 @@ def q8_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, s_x: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=backend.interpret_mode(),
     )(xp, wp, sx, swp)
     return out[:M, :N]

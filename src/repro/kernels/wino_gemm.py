@@ -23,8 +23,9 @@ tensor in the pipeline.  The arithmetic (fp32 multiply, round-half-even,
 clip) is exactly the staged formula, so the epilogue output is
 bit-identical to the staged requant.
 
-The TPU is the *target*; correctness is validated in ``interpret=True``
-mode against ``ref.wino_gemm_ref`` (exact integer equality).
+On a TPU the kernel is compiled by Mosaic; on the CPU backend it runs
+in Pallas interpret mode (``kernels.backend``), where correctness is
+validated against ``ref.wino_gemm_ref`` (exact integer equality).
 """
 from __future__ import annotations
 
@@ -36,8 +37,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.quantization import qmax
+from repro.kernels import backend
+from repro.kernels.backend import smem_spec
 
 __all__ = ["wino_gemm", "requant_plane", "DEFAULT_BLOCKS",
+           "INT8_DOT_PRECISION",
            "default_blocks", "validate_blocks", "MAX_BLOCK",
            "INT32_ACC_LIMIT", "FP32_EXACT_INT_LIMIT",
            "max_abs_accumulator"]
@@ -48,6 +52,12 @@ __all__ = ["wino_gemm", "requant_plane", "DEFAULT_BLOCKS",
 #: K = Cin grid in int32 — the static range certifier
 #: (``repro.analysis.ranges``) proves configs against exactly this bound.
 INT32_ACC_LIMIT = 2 ** 31 - 1
+
+#: Precision of every int8×int8→int32 dot in the kernels, stated so a
+#: process-wide ``jax_default_matmul_precision`` (e.g. "highest" for an
+#: fp32 reference) never reaches them: Mosaic refuses an fp32-precision
+#: contraction of int8 operands, and integer products are exact anyway.
+INT8_DOT_PRECISION = jax.lax.Precision.DEFAULT
 
 #: Largest integer magnitude fp32 represents exactly (24-bit mantissa).
 #: ``requant_plane`` casts the int32 accumulator to fp32 before the
@@ -143,12 +153,15 @@ def _gemm_kernel(x_ref, w_ref, o_ref):
     o_ref[0, ...] += jax.lax.dot_general(
         x_ref[0], w_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=INT8_DOT_PRECISION,
         preferred_element_type=jnp.int32,
     )
 
 
 def _gemm_requant_kernel(x_ref, w_ref, deq_ref, rq_ref, o_ref, *, qm: int):
     """GEMM block with the Hadamard-requant epilogue on the last K step."""
+    p = pl.program_id(0)                # this block's Winograd position
+
     @pl.when(pl.program_id(3) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -156,12 +169,13 @@ def _gemm_requant_kernel(x_ref, w_ref, deq_ref, rq_ref, o_ref, *, qm: int):
     o_ref[0, ...] += jax.lax.dot_general(
         x_ref[0], w_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=INT8_DOT_PRECISION,
         preferred_element_type=jnp.int32,
     )
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _epilogue():
-        q = requant_plane(o_ref[0, ...], deq_ref[0, 0], rq_ref[0, 0], qm)
+        q = requant_plane(o_ref[0, ...], deq_ref[p], rq_ref[p], qm)
         o_ref[0, ...] = q.astype(jnp.int32)
 
 
@@ -174,11 +188,9 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     return jnp.pad(x, cfg)
 
 
-@functools.partial(jax.jit, static_argnames=("blocks", "interpret",
-                                             "requant_bits"))
+@functools.partial(jax.jit, static_argnames=("blocks", "requant_bits"))
 def wino_gemm(x: jnp.ndarray, w: jnp.ndarray,
               blocks: tuple[int, int, int] | None = None,
-              interpret: bool = False,
               requant_bits: int | None = None,
               deq: jnp.ndarray | None = None,
               rq: jnp.ndarray | None = None) -> jnp.ndarray:
@@ -216,15 +228,15 @@ def wino_gemm(x: jnp.ndarray, w: jnp.ndarray,
     else:
         kernel = functools.partial(_gemm_requant_kernel,
                                    qm=qmax(requant_bits))
-        scale_spec = pl.BlockSpec((1, 1), lambda p, i, j, k: (p, 0))
-        in_specs = gemm_specs + [scale_spec, scale_spec]
-        operands = (xp, wp, deq, rq)
+        # The per-position scales are read as scalars from SMEM.
+        in_specs = gemm_specs + [smem_spec(), smem_spec()]
+        operands = (xp, wp, deq.reshape(-1), rq.reshape(-1))
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda p, i, j, k: (p, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Mp, Np), jnp.int32),
-        interpret=interpret,
+        interpret=backend.interpret_mode(),
     )(*operands)
     return out[:, :M, :N]

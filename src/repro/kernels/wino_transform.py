@@ -1,27 +1,27 @@
 """Pallas TPU kernels: fused Winograd input/output transforms (+(de)quant).
 
-These are the bandwidth-bound stages of the Winograd pipeline.  On TPU the
-profitable layout keeps channels on the 128-lane minor dimension and the
-tile grid on the sublane dimension, so a block is ``(bt, bc)`` tiles×chans
-with the n×n tile window unrolled into registers — the 6×6 transform
-sandwiches become a fixed sequence of VPU multiply-adds with matrix
-constants (never worth MXU latency at 6×6).
+These are the bandwidth-bound stages of the Winograd pipeline. Every
+block keeps the tile and channel axes (T, C) as its two minor axes —
+channels on the 128 lanes, tiles on the sublanes — and the n×n tile
+window on the leading axis, position-major (``p = a·n + b``). Each
+sandwich term is then a scalar times one ``(bt, bc)`` plane: pure VPU
+multiply-adds, with no minor-axis reshape for Mosaic to lay out. The
+spatial permutation between NHWC and this layout is XLA data movement
+in ``kernels.ops`` (``_extract`` / ``_reassemble``).
 
 Input transform (fused, one HBM round-trip):
-    tiles (T, C, n, n) fp32  →  C⁻ᵀ·X·C⁻¹ → B_Cᵀ·(·)·B_C → scale→round→clip
-    → (n², T, C) int8 laid out for `wino_gemm` (position-major).
+    tiles (n², T, C) fp32  →  C⁻ᵀ·X·C⁻¹ → B_Cᵀ·(·)·B_C → scale→round→clip
+    → (n², T, C) int8, laid out for ``wino_gemm``.
 
 Output transform:
     H (n², T, C) int32  →  ·deq scale → C⁻ᵀ·(·)·C⁻¹ → A_Cᵀ·(·)·A_C
-    → (T, C, m, m) fp32.
+    → (m², T, C) fp32.
 
-The transform matrices arrive as kernel operands (fp32, whole-array
-blocks): for the *flex* variants they are learnable tensors, so they must
-not be baked into the kernel as compile-time constants.
-
-Scales are computed OUTSIDE the kernel (a cheap XLA reduction) and passed
-in; this keeps the kernel single-pass.  Per-position scales arrive as an
-(n², 1) operand (broadcast against the block).
+The transform matrices and the per-position scales are kernel operands
+read as scalars from SMEM: for the *flex* variants the matrices are
+learnable tensors, so they must not be baked into the kernel as
+compile-time constants. Scales are computed OUTSIDE the kernel (a cheap
+XLA reduction) and passed in; this keeps the kernel single-pass.
 """
 from __future__ import annotations
 
@@ -31,92 +31,104 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["input_transform", "output_transform", "sandwich_stack"]
+from repro.kernels import backend
+from repro.kernels.backend import smem_spec
 
-#: Largest tile-window size the unrolled scalar sandwich is used for.
-#: The unrolled form emits O(n_out²·n_in²) scalar multiply-adds — fine
-#: at F(2,3)/F(4,3) (n ≤ 6, ≤ 1296 terms) but at F(6,3)'s n = 8 the
-#: base-change sandwich alone is 4096 terms, which blows up XLA compile
-#: time (minutes in interpret mode) and is VPU-latency-bound on
-#: hardware. Larger windows route through two dot_generals instead
-#: (MXU work at sizes where the systolic array starts to pay).
-#: F(2,3)/F(4,3) keep the unrolled path — and their committed bitwise
-#: parity behavior — unchanged.
-_UNROLL_MAX_N = 6
+__all__ = ["input_transform", "output_transform", "sandwich",
+           "scalars", "output_planes"]
 
 
-def _sandwich_unrolled(mat_l, mat_r_t, x, n_in, n_out):
-    """out[a,b] = Σ_{j,k} L[a,j]·x[...,j,k]·Rᵀ[b,k] with x (bt,bc,n,n).
+#: Largest tile window the interpret-mode kernels unroll. Beyond it
+#: (F(6,3): n = 8) XLA's CPU compile of the 2·n_out·n_in² unrolled
+#: plane terms takes several times longer than the whole call, so in
+#: interpret mode the sandwich runs as two small dot_generals instead.
+#: Mosaic always unrolls: it has no such cost, and no leading-axis dot.
+_INTERPRET_UNROLL_MAX_N = 6
 
-    Unrolled over the (small, static) tile window; each term is a scalar
-    constant × (bt,bc) plane — pure VPU work.
+
+def _sandwich_dot(mat_l, mat_r, x, n_in: int, n_out: int):
+    """The sandwich as two dot_generals over the window stacked on the
+    minor axes, (..., n, n): the layout whose CPU contraction gives the
+    staged and fused interpret-mode kernels the same rounding at any
+    plane shape."""
+    plane = x[0].shape
+    L = jnp.stack(mat_l).reshape(n_out, n_in)
+    R = jnp.stack(mat_r).reshape(n_out, n_in)
+    xs = jnp.stack(x, -1).reshape(*plane, n_in, n_in)
+    t = jnp.einsum("aj,...jk->...ak", L, xs)
+    out = jnp.einsum("bk,...ak->...ab", R, t).reshape(*plane, -1)
+    return [out[..., p] for p in range(n_out * n_out)]
+
+
+def sandwich(mat_l, mat_r, x, n_in: int, n_out: int):
+    """L·X·Rᵀ over a position-major list of n_in² planes → n_out² planes.
+
+    THE sandwich of every transform kernel (input, output, fused
+    serving), so the staged and fused pipelines run identical
+    arithmetic. ``mat_l``/``mat_r`` are row-major flattened (n_out, n_in)
+    matrices as lists of scalars (``scalars`` of an SMEM ref). Separable,
+    in the order of the two matrix products it stands for — rows
+    t[a,k] = Σ_j L[a,j]·x[j,k], then columns out[a,b] = Σ_k R[b,k]·t[a,k]
+    — so it costs 2·n_out·n_in² scalar×plane multiply-adds (432 at
+    F(4,3)) instead of the n_out²·n_in² of the four-index form.
     """
-    planes = [[None] * n_out for _ in range(n_out)]
+    if n_in > _INTERPRET_UNROLL_MAX_N and backend.interpret_mode():
+        return _sandwich_dot(mat_l, mat_r, x, n_in, n_out)
+    t = []
+    for a in range(n_out):
+        for k in range(n_in):
+            acc = None
+            for j in range(n_in):
+                contrib = x[j * n_in + k] * mat_l[a * n_in + j]
+                acc = contrib if acc is None else acc + contrib
+            t.append(acc)
+    out = []
     for a in range(n_out):
         for b in range(n_out):
             acc = None
-            for j in range(n_in):
-                for k in range(n_in):
-                    term = mat_l[a, j] * mat_r_t[b, k]
-                    contrib = x[..., j, k] * term
-                    acc = contrib if acc is None else acc + contrib
-            planes[a][b] = acc
-    return planes
+            for k in range(n_in):
+                contrib = t[a * n_in + k] * mat_r[b * n_in + k]
+                acc = contrib if acc is None else acc + contrib
+            out.append(acc)
+    return out
 
 
-def _sandwich_dot(mat_l, mat_r_t, x):
-    """L · x · Rᵀ over the trailing two dims of x, as two dot_generals."""
-    t = jnp.einsum("aj,...jk->...ak", mat_l, x)
-    return jnp.einsum("bk,...ak->...ab", mat_r_t, t)
+def scalars(ref, size: int) -> list:
+    """Every entry of a 1-D SMEM ref, each read once as a scalar."""
+    return [ref[i] for i in range(size)]
 
 
-def sandwich_stack(mat_l, mat_r_t, x, n_in: int, n_out: int):
-    """Transform sandwich → stacked (..., n_out, n_out) array.
-
-    THE shared sandwich of every transform kernel (input, output, fused
-    serving) — one strategy per window size, so the staged and fused
-    pipelines always run identical arithmetic. Small windows (n ≤ 6)
-    keep the unrolled scalar form; larger windows (F(6,3): n = 8) use
-    the dot_general form (see ``_UNROLL_MAX_N``).
-    """
-    if n_in <= _UNROLL_MAX_N:
-        planes = _sandwich_unrolled(mat_l, mat_r_t, x, n_in, n_out)
-        return jnp.stack([jnp.stack(row, -1) for row in planes], -2)
-    return _sandwich_dot(mat_l, mat_r_t, x)
+def output_planes(h, cinvt_ref, apt_ref, n: int, m: int,
+                  changes_base: bool):
+    """Dequantized Hadamard planes (n², list) → output planes (m², list):
+    the output-transform sandwich shared by the staged and fused kernels."""
+    if changes_base:
+        cinvt = scalars(cinvt_ref, n * n)
+        h = sandwich(cinvt, cinvt, h, n, n)
+    apt = scalars(apt_ref, m * n)
+    return sandwich(apt, apt, h, n, m)
 
 
 def _input_kernel(tiles_ref, cinvt_ref, bpt_ref, scale_ref, out_ref, *,
                   n: int, changes_base: bool):
-    x = tiles_ref[...].astype(jnp.float32)          # (bt, bc, n, n)
-    cinvt = cinvt_ref[...]
-    bpt = bpt_ref[...]
+    x = [tiles_ref[p].astype(jnp.float32) for p in range(n * n)]
     if changes_base:
-        # stacking rows at -2 and cols at -1 lands (bt, bc, n, n) in
-        # row-major tile order — verified exactly against
-        # ref.input_transform_fp for the base-change path.
-        x = sandwich_stack(cinvt, cinvt, x, n, n)
-    v = sandwich_stack(bpt, bpt, x, n, n)
-    # quantize per position: scale_ref is (n*n, 1) in SMEM-like layout
-    for a in range(n):
-        for b in range(n):
-            p = a * n + b
-            s = scale_ref[p, 0]
-            q = jnp.clip(jnp.round(v[..., a, b] / s), -127, 127)
-            out_ref[p, ...] = q.astype(jnp.int8)
+        cinvt = scalars(cinvt_ref, n * n)
+        x = sandwich(cinvt, cinvt, x, n, n)
+    bpt = scalars(bpt_ref, n * n)
+    v = sandwich(bpt, bpt, x, n, n)
+    for p, s in enumerate(scalars(scale_ref, n * n)):
+        q = jnp.clip(jnp.round(v[p] / s), -127, 127)
+        out_ref[p] = q.astype(jnp.int32).astype(jnp.int8)
 
 
 def _output_kernel(h_ref, scale_ref, cinvt_ref, apt_ref, out_ref, *,
                    n: int, m: int, changes_base: bool):
-    # h_ref: (n², bt, bc) int32 → dequantize per position → sandwich → (m,m)
-    cols = []
-    for p in range(n * n):
-        cols.append(h_ref[p, ...].astype(jnp.float32) * scale_ref[p, 0])
-    h = jnp.stack(cols, -1).reshape(*cols[0].shape, n, n)   # (bt, bc, n, n)
-    cinvt = cinvt_ref[...]
-    apt = apt_ref[...]
-    if changes_base:
-        h = sandwich_stack(cinvt, cinvt, h, n, n)
-    out_ref[...] = sandwich_stack(apt, apt, h, n, m)        # (bt,bc,m,m)
+    h = [h_ref[p].astype(jnp.float32) * s
+         for p, s in enumerate(scalars(scale_ref, n * n))]
+    for p, y in enumerate(output_planes(h, cinvt_ref, apt_ref, n, m,
+                                        changes_base)):
+        out_ref[p] = y
 
 
 def _pad_axis(x, axis, mult):
@@ -128,49 +140,48 @@ def _pad_axis(x, axis, mult):
     return jnp.pad(x, cfg)
 
 
-@functools.partial(jax.jit, static_argnames=("changes_base", "block",
-                                             "interpret"))
+def _window(P: int) -> int:
+    n = int(round(P ** 0.5))
+    assert n * n == P, P
+    return n
+
+
+@functools.partial(jax.jit, static_argnames=("changes_base", "block"))
 def input_transform(tiles: jnp.ndarray, cinvt: jnp.ndarray, bpt: jnp.ndarray,
                     pos_scale: jnp.ndarray, *, changes_base: bool = True,
-                    block: tuple[int, int] = (8, 128),
-                    interpret: bool = False) -> jnp.ndarray:
-    """tiles (T, C, n, n) fp32 → (n², T, C) int8 (position-major for GEMM).
+                    block: tuple[int, int] = (32, 128)) -> jnp.ndarray:
+    """tiles (n², T, C) fp32 → (n², T, C) int8 (position-major for GEMM).
 
-    ``pos_scale``: (n², 1) fp32 quantization scales (per position; replicate
-    a per-tensor scale to all n² rows for the paper-faithful mode).
+    ``pos_scale``: (n², 1) fp32 quantization scales (per position;
+    replicate a per-tensor scale to all n² rows for the paper-faithful
+    mode). The default tile block is 32 rows, the int8 sublane tiling of
+    the output block.
     """
-    T, C, n, _ = tiles.shape
+    P, T, C = tiles.shape
+    n = _window(P)
     bt, bc = min(block[0], T), min(block[1], C)
-    tp = _pad_axis(_pad_axis(tiles, 0, bt), 1, bc)
-    Tp, Cp = tp.shape[0], tp.shape[1]
-    grid = (Tp // bt, Cp // bc)
+    tp = _pad_axis(_pad_axis(tiles, 1, bt), 2, bc)
+    Tp, Cp = tp.shape[1], tp.shape[2]
     out = pl.pallas_call(
         functools.partial(_input_kernel, n=n, changes_base=changes_base),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, bc, n, n), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((n, n), lambda i, j: (0, 0)),
-            pl.BlockSpec((n, n), lambda i, j: (0, 0)),
-            pl.BlockSpec((n * n, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n * n, bt, bc), lambda i, j: (0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((n * n, Tp, Cp), jnp.int8),
-        interpret=interpret,
-    )(tp, cinvt, bpt, pos_scale)
+        grid=(Tp // bt, Cp // bc),
+        in_specs=[pl.BlockSpec((P, bt, bc), lambda i, j: (0, i, j)),
+                  smem_spec(), smem_spec(), smem_spec()],
+        out_specs=pl.BlockSpec((P, bt, bc), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((P, Tp, Cp), jnp.int8),
+        interpret=backend.interpret_mode(),
+    )(tp, cinvt.reshape(-1), bpt.reshape(-1), pos_scale.reshape(-1))
     return out[:, :T, :C]
 
 
-@functools.partial(jax.jit, static_argnames=("m", "changes_base", "block",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("m", "changes_base", "block"))
 def output_transform(h: jnp.ndarray, pos_scale: jnp.ndarray,
                      cinvt: jnp.ndarray, apt: jnp.ndarray, *, m: int,
                      changes_base: bool = True,
-                     block: tuple[int, int] = (8, 128),
-                     interpret: bool = False) -> jnp.ndarray:
-    """H (n², T, C) int32 (+ per-position dequant scales) → (T, C, m, m)."""
+                     block: tuple[int, int] = (8, 128)) -> jnp.ndarray:
+    """H (n², T, C) int32 (+ per-position dequant scales) → (m², T, C)."""
     P, T, C = h.shape
-    n = int(round(P ** 0.5))
-    assert n * n == P
+    n = _window(P)
     # Shape-stability contract: the 2-D sharded dynamic-requant path runs
     # this transform per device on a (T/D_data, C/D_model) slab and
     # asserts bitwise equality with the full-tensor call, so the compiled
@@ -178,38 +189,35 @@ def output_transform(h: jnp.ndarray, pos_scale: jnp.ndarray,
     # achieve that: (a) bt is NOT clamped to T — the tile-block shape is
     # the same for a 5-row slab and the full tensor (zero padding covers
     # T < bt; zero rows transform to zero rows and are cropped below);
-    # (b) the grid always has ≥ 2 steps — a single-step pallas_call gets
-    # inlined into the surrounding jit and XLA re-fuses/contracts its
-    # multiply-adds, while the multi-step grid loop is a fusion barrier
-    # whose per-block program is identical at every grid size AND block
-    # shape (verified: grid 2 and grid 3 agree bitwise across differing
-    # block shapes, either disagrees with grid 1 in the last fp32 bit).
-    # When a call would compile to one step, split the channel block in
-    # half (same total work, one extra step) rather than padding a whole
-    # all-zero tile block; padding is the fallback for odd/1-channel.
+    # (b) in interpret mode the grid always has ≥ 2 steps — a single-step
+    # pallas_call gets inlined into the surrounding jit and XLA re-fuses/
+    # contracts its multiply-adds, while the multi-step grid loop is a
+    # fusion barrier whose per-block program is identical at every grid
+    # size AND block shape. When a call would compile to one step, split
+    # the channel block in half (same total work, one extra step) rather
+    # than padding a whole all-zero tile block; padding is the fallback
+    # for odd/1-channel. Mosaic compiles one kernel body per block shape
+    # that XLA never inlines, so on a TPU rule (b) is not needed — and
+    # its half-lane channel blocks would break the (8, 128) block rule.
     bt, bc = block[0], min(block[1], C)
-    if -(-T // bt) == 1 and -(-C // bc) == 1:
+    split = backend.interpret_mode()
+    if split and -(-T // bt) == 1 and -(-C // bc) == 1:
         if bc % 2 == 0:
             bc //= 2
         else:
             bt = max(1, (T + 1) // 2)
     hp = _pad_axis(_pad_axis(h, 1, bt), 2, bc)
-    if hp.shape[1] // bt == 1 and hp.shape[2] // bc == 1:
+    if split and hp.shape[1] // bt == 1 and hp.shape[2] // bc == 1:
         hp = _pad_axis(hp, 1, 2 * bt)    # T == C == 1: nothing to split
     Tp, Cp = hp.shape[1], hp.shape[2]
-    grid = (Tp // bt, Cp // bc)
     out = pl.pallas_call(
         functools.partial(_output_kernel, n=n, m=m,
                           changes_base=changes_base),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n * n, bt, bc), lambda i, j: (0, i, j)),
-            pl.BlockSpec((n * n, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((n, n), lambda i, j: (0, 0)),
-            pl.BlockSpec((m, n), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, bc, m, m), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Tp, Cp, m, m), jnp.float32),
-        interpret=interpret,
-    )(hp, pos_scale, cinvt, apt)
-    return out[:T, :C]
+        grid=(Tp // bt, Cp // bc),
+        in_specs=[pl.BlockSpec((P, bt, bc), lambda i, j: (0, i, j)),
+                  smem_spec(), smem_spec(), smem_spec()],
+        out_specs=pl.BlockSpec((m * m, bt, bc), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((m * m, Tp, Cp), jnp.float32),
+        interpret=backend.interpret_mode(),
+    )(hp, pos_scale.reshape(-1), cinvt.reshape(-1), apt.reshape(-1))
+    return out[:, :T, :C]

@@ -72,6 +72,8 @@ from repro.conv import Plan, PlanEntry, build_plan, plan_cost_us
 from repro.core.quantization import QuantConfig
 from repro.core.winograd import WinogradSpec
 from repro.data.pipeline import cifar_batch_at
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import require_host_devices
 from repro.models import resnet as RN
 from repro.models.param import init_params
 
@@ -91,8 +93,9 @@ def main(argv=None):
     ap.add_argument("--calib-steps", type=int, default=4)
     ap.add_argument("--ckpt-dir", default="/tmp/resnet_int8_ckpt")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="split the host CPU into N XLA devices for the "
-                         "sharded-serving demo (re-execs with XLA_FLAGS)")
+                    help="CPU only: split the host CPU into N XLA devices "
+                         "for the sharded-serving demo (re-execs with "
+                         "XLA_FLAGS)")
     ap.add_argument("--autotune", action="store_true",
                     help="tune the fused kernel's Pallas (bm, bn, bk) "
                          "block split per layer shape at calibration "
@@ -119,14 +122,11 @@ def main(argv=None):
     if args.calib_steps < 1:
         ap.error("--calib-steps must be >= 1 (int8 serving needs "
                  "calibrated scales)")
-    if args.host_devices > 0 and len(jax.devices()) < args.host_devices:
-        # The XLA_FLAGS re-exec only runs when launched as a script; a
-        # programmatic main([...]) call lands here with the backend
-        # already fixed — say so instead of silently serving 1-device.
-        print(f"[warn] --host-devices {args.host_devices} requested but "
-              f"jax sees {len(jax.devices())} device(s); the re-exec "
-              "only applies when run as `python -m "
-              "repro.launch.infer_resnet` before jax initializes")
+    # The XLA_FLAGS re-exec only runs when launched as a script; a
+    # programmatic main([...]) call, or a TPU host, lands here with the
+    # backend already fixed — refuse rather than serve a smaller mesh.
+    require_host_devices(args.host_devices)
+    enable_compile_cache()
 
     cfg = RN.ResNetConfig(
         width_mult=args.width,
@@ -273,7 +273,7 @@ def main(argv=None):
     print(f"[serve] wall: fused {t_prep * 1e3:.0f}ms vs staged "
           f"{t_staged * 1e3:.0f}ms vs dynamic {t_dyn * 1e3:.0f}ms per batch "
           f"({t_dyn / max(t_prep, 1e-9):.2f}× over dynamic, "
-          f"interpret-mode CPU)")
+          f"{jax.default_backend()})")
 
     if args.autotune:
         # Autotuned-vs-default serving row: the restored engine carries
@@ -294,7 +294,7 @@ def main(argv=None):
         print(f"[serve] autotuned blocks {t_prep * 1e3:.0f}ms vs default "
               f"blocks {t_def * 1e3:.0f}ms per batch "
               f"({t_def / max(t_prep, 1e-9):.2f}× from tuning, "
-              f"interpret-mode CPU; per-layer wins don't always survive "
+              f"{jax.default_backend()}; per-layer wins don't always survive "
               "the outer jit here — the kernel-level rows in "
               "BENCH_kernel.json are the tuner's contract)")
         # Per layer a block split only re-tiles exact integer work (fp32

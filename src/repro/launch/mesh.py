@@ -18,17 +18,19 @@ import sys
 import jax
 
 __all__ = ["make_production_mesh", "make_mesh_for", "make_host_mesh",
-           "ensure_host_device_count"]
+           "ensure_host_device_count", "require_host_devices",
+           "serving_devices"]
 
 
 def ensure_host_device_count(n: int, module: str, argv) -> None:
     """Re-exec ``python -m module argv`` with the host CPU split into
     ``n`` XLA devices (the ``--host-devices`` knob of the serving
-    launcher and benchmarks — a local multi-device demo without TPUs).
+    launcher and benchmarks — a CPU-only multi-device demo).
 
     XLA fixes the device count at backend *initialization*, so the flag
     must be in the environment before the first jax computation; callers
-    invoke this from their entry point before any timing/serving work.
+    invoke this from their entry point before any timing/serving work,
+    and ``require_host_devices`` checks the result once jax is up.
     No-op when ``n <= 0`` or the flag is already set (the re-exec'd
     child takes this branch).
     """
@@ -39,6 +41,35 @@ def ensure_host_device_count(n: int, module: str, argv) -> None:
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={n}").strip()
     os.execv(sys.executable, [sys.executable, "-m", module] + list(argv))
+
+
+def require_host_devices(n: int) -> None:
+    """Refuse a ``--host-devices`` request jax cannot honour: the knob
+    splits the host CPU, so on an accelerator backend it is an error,
+    and on the CPU the split must have happened before jax started."""
+    if n <= 0:
+        return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise ValueError(f"--host-devices splits the host CPU into XLA "
+                         f"devices; this host serves on {backend!r} — "
+                         "drop the flag and mesh over its own devices")
+    if len(jax.devices()) < n:
+        raise ValueError(f"--host-devices {n} requested but jax sees "
+                         f"{len(jax.devices())} device(s): the split only "
+                         "applies when the launcher runs as `python -m` "
+                         "before jax initializes")
+
+
+def serving_devices(n: int) -> list:
+    """The first ``n`` visible devices for a serving mesh; a request for
+    more devices than this process sees raises (never shrinks)."""
+    devs = jax.devices()
+    if n > len(devs):
+        raise ValueError(f"a serving mesh of {n} devices needs {n}, but "
+                         f"jax sees {len(devs)} {devs[0].platform} "
+                         "device(s)")
+    return devs[:n]
 
 
 def make_production_mesh(*, multi_pod: bool = False):

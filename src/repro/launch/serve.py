@@ -58,6 +58,8 @@ from repro.conv import Plan, PlanEntry, build_plan
 from repro.core.quantization import QuantConfig
 from repro.core.winograd import WinogradSpec
 from repro.data.pipeline import cifar_batch_at
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import require_host_devices, serving_devices
 from repro.models import resnet as RN
 from repro.models.param import init_params
 from repro.serving import (ServeConfig, ServingLoop, run_poisson_load,
@@ -124,21 +126,15 @@ def make_served_engine(args, cfg, template):
         from jax.sharding import Mesh
         dd = max(args.mesh_devices, 1)
         dm = max(args.model_devices, 1)
-        ndev = len(jax.devices())
-        if dd * dm > ndev:
-            print(f"[warn] --mesh-devices {dd} × --model-devices {dm} > "
-                  f"visible devices {ndev}; shrinking the data axis "
-                  "(pass --host-devices to split the host CPU)")
-            dd = max(ndev // dm, 1)
+        devs = serving_devices(dd * dm)       # raises rather than shrink
         if dm > 1:
-            devs = np.array(jax.devices()[:dd * dm]).reshape(dd, dm)
-            mesh = Mesh(devs, ("data", "model"))
+            mesh = Mesh(np.array(devs).reshape(dd, dm), ("data", "model"))
             model_axis = "model"
             print(f"[mesh] serving across {dd}×{dm} (data × model) "
                   "devices: tiles × Cout shard_map, weights "
                   f"cout-sharded 1/{dm} per device")
         else:
-            mesh = Mesh(np.array(jax.devices()[:dd]), ("data",))
+            mesh = Mesh(np.array(devs), ("data",))
             print(f"[mesh] serving across {dd} device(s), tile-axis "
                   "shard_map")
         from repro.conv.packing import packed_tree_shardings
@@ -158,7 +154,9 @@ def make_served_engine(args, cfg, template):
     return engine
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's options (``chip_smoke.py`` parses its settings
+    through this same parser)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--width", type=float, default=0.25)
     ap.add_argument("--base", default="legendre",
@@ -205,17 +203,29 @@ def main(argv=None):
                          "each layer's Cout (and 1/M of the packed "
                          "weight bytes) per device")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="split the host CPU into N XLA devices "
+                    help="CPU only: split the host CPU into N XLA devices "
                          "(re-execs with XLA_FLAGS; for --mesh-devices)")
-    args = ap.parse_args(argv)
-    if args.calib_steps < 1:
-        ap.error("--calib-steps must be >= 1")
-    buckets = tuple(int(b) for b in args.buckets.split(","))
+    return ap
 
-    cfg = RN.ResNetConfig(
+
+def make_config(args) -> RN.ResNetConfig:
+    """The served model: ResNet-18 at ``--width`` with F(4,3) in
+    ``--base`` and the 9-bit Hadamard stage."""
+    return RN.ResNetConfig(
         width_mult=args.width,
         wino=WinogradSpec(m=4, r=3, base=args.base,
                           quant=QuantConfig(hadamard_bits=9)))
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    if args.calib_steps < 1:
+        ap.error("--calib-steps must be >= 1")
+    require_host_devices(args.host_devices)
+    enable_compile_cache()
+    buckets = tuple(int(b) for b in args.buckets.split(","))
+    cfg = make_config(args)
 
     # Offline: pack → calibrate → checkpoint (stage 1).
     params, state, template = build_serving_state(args, cfg)

@@ -150,8 +150,8 @@ def _bn(x, p, st, training: bool, momentum: float):
 
 
 def make_engine(cfg: ResNetConfig, backend: Optional[str] = None,
-                fused: bool = True, interpret: bool = True,
-                mesh=None, data_axis="data", model_axis=None,
+                fused: bool = True, mesh=None, data_axis="data",
+                model_axis=None,
                 blocks: Optional[tuple] = None,
                 autotune: bool = False,
                 autotune_opts: Optional[dict] = None,
@@ -194,7 +194,7 @@ def make_engine(cfg: ResNetConfig, backend: Optional[str] = None,
     else:
         backend = backend or cfg.conv_backend or "winograd_fakequant"
         eng = ConvEngine(cfg.wino, ConvPolicy(backend=backend),
-                         fused=fused, interpret=interpret, mesh=mesh,
+                         fused=fused, mesh=mesh,
                          data_axis=data_axis, model_axis=model_axis,
                          blocks=blocks, autotune=autotune,
                          autotune_opts=autotune_opts, plan=plan)

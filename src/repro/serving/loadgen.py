@@ -37,6 +37,8 @@ class LoadReport:
     padding_frac: float          # padded rows / dispatched rows
     busy_frac: float             # approximate device utilization
     compiles: Optional[int]      # XLA programs built during the run
+    outputs: list = dataclasses.field(default_factory=list, repr=False)
+    # per request, in submission order: the served output row(s)
 
     @property
     def throughput_rps(self) -> float:
@@ -83,8 +85,7 @@ def run_poisson_load(loop, rate_rps: float, n_requests: int,
         if lag > 0:
             time.sleep(lag)
         futures.append(loop.submit(make_request(i), client="loadgen"))
-    for f in futures:
-        f.result()
+    outputs = [f.result() for f in futures]
     wall = time.perf_counter() - t0
 
     recs = loop.records[first_rec:]
@@ -97,7 +98,7 @@ def run_poisson_load(loop, rate_rps: float, n_requests: int,
         mean_batch=real / max(len(batches), 1),
         padding_frac=0.0 if rows == 0 else 1.0 - real / rows,
         busy_frac=loop.busy_fraction(wall),
-        compiles=loop.compiles_after_warmup)
+        compiles=loop.compiles_after_warmup, outputs=outputs)
 
 
 def solo_latencies(forward, requests: Sequence[np.ndarray],

@@ -77,10 +77,10 @@ def test_autotune_blocks_are_numerics_neutral():
     x = jax.random.normal(KEY, (2, 16, 16, 8))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 8, 12)) * 0.2
     y_default = winograd_conv2d_int8(x, w, spec, hadamard_bits=9,
-                                     fused=True, interpret=True)
+                                     fused=True)
     for blocks in [(8, 8, 8), (16, 12, 8)]:
         y = winograd_conv2d_int8(x, w, spec, hadamard_bits=9, fused=True,
-                                 blocks=blocks, interpret=True)
+                                 blocks=blocks)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_default),
                                    rtol=1e-4, atol=1e-4)
 
@@ -173,8 +173,7 @@ def test_bad_blocks_rejected_at_engine_and_execute(bad):
     x = jax.random.normal(KEY, (1, 8, 8, 4))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 4, 4)) * 0.2
     with pytest.raises(ValueError):
-        winograd_conv2d_int8(x, w, spec, hadamard_bits=9, blocks=bad,
-                             interpret=True)
+        winograd_conv2d_int8(x, w, spec, hadamard_bits=9, blocks=bad)
 
 
 def test_valid_blocks_pass_validation():

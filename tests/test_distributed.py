@@ -121,16 +121,10 @@ def test_grad_compression_ring_allreduce():
             out, err = compressed_grad_mean(grads, errs, 2)
             return out["w"], err["w"]
 
-        if hasattr(jax, "shard_map"):        # jax >= 0.5
-            def sm(f):
-                return jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
-                                     out_specs=(P(), P("pod")),
-                                     axis_names={"pod"}, check_vma=False)
-        else:                                # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
-            def sm(f):
-                return shard_map(f, mesh=mesh, in_specs=P("pod"),
-                                 out_specs=(P(), P("pod")), check_rep=False)
+        def sm(f):
+            return jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                 out_specs=(P(), P("pod")),
+                                 axis_names={"pod"}, check_vma=False)
 
         fn = jax.jit(sm(f))
         mean, err = fn(g_global)
@@ -210,40 +204,37 @@ def test_sharded_fused_serving_parity():
                     in_s = scales_from_abs_max(_tiles_abs_max(tiles, spec))
                     _, amax = execute_int8(
                         tiles, u_q, w_s, in_s, spec=spec, geom=geom,
-                        hadamard_bits=bits, interpret=True,
+                        hadamard_bits=bits,
                         with_stats=True)
                     h_amax = amax.reshape(-1, 1)
                     deq = in_s * w_s
                     rq = jnp.maximum(h_amax, 1e-12) / qmax(bits)
                     Xq = input_transform(tiles, mats.CinvT, mats.BPT,
                                          in_s,
-                                         changes_base=spec.changes_base,
-                                         interpret=True)
+                                         changes_base=spec.changes_base)
                     # single-device fused kernel on the full tile tensor
                     ref = np.asarray(_reassemble(fused_gemm_output(
                         Xq, u_q, deq, rq, mats.CinvT, mats.APT, m=m,
                         requant_bits=bits,
-                        changes_base=spec.changes_base,
-                        interpret=True), geom, m))
+                        changes_base=spec.changes_base), geom, m))
                     for d in (1, 2, 4):
                         mesh = Mesh(np.array(jax.devices()[:d]),
                                     ("data",))
                         y = np.asarray(execute_int8_sharded(
                             tiles, u_q, w_s, in_s, h_amax, spec=spec,
-                            geom=geom, mesh=mesh, hadamard_bits=bits,
-                            interpret=True))
+                            geom=geom, mesh=mesh, hadamard_bits=bits))
                         assert np.array_equal(y, ref), \\
                             (m, base, bits, d, np.abs(y - ref).max())
                     # Hadamard-domain integers: per-slab GEMM+requant
                     # epilogue == the matching slice of the global plane
-                    H = np.asarray(wino_gemm(Xq, u_q, interpret=True,
+                    H = np.asarray(wino_gemm(Xq, u_q,
                                              requant_bits=bits, deq=deq,
                                              rq=rq))
                     T = Xq.shape[1]
                     for d in (2, 4):
                         parts = [np.asarray(wino_gemm(
                             Xq[:, i * T // d:(i + 1) * T // d], u_q,
-                            interpret=True, requant_bits=bits, deq=deq,
+                            requant_bits=bits, deq=deq,
                             rq=rq)) for i in range(d)]
                         assert np.array_equal(
                             np.concatenate(parts, axis=1), H), \\
@@ -345,25 +336,23 @@ def test_one_xq_across_modes_and_f63_sharded():
                 in_s = scales_from_abs_max(_tiles_abs_max(tiles, spec))
                 _, amax = execute_int8(
                     tiles, u_q, w_s, in_s, spec=spec, geom=geom,
-                    hadamard_bits=9, interpret=True, with_stats=True)
+                    hadamard_bits=9, with_stats=True)
                 h_amax = amax.reshape(-1, 1)
                 # the one compile unit every mode dispatches
-                Xq = quantize_input(tiles, in_s, spec=spec,
-                                    interpret=True)
+                Xq = quantize_input(tiles, in_s, spec=spec)
                 deq = in_s * w_s
                 rq = jnp.maximum(h_amax, 1e-12) / qmax(9)
                 ref = np.asarray(_reassemble(fused_gemm_output(
                     Xq, u_q, deq, rq, mats.CinvT, mats.APT, m=m,
-                    requant_bits=9, changes_base=spec.changes_base,
-                    interpret=True), geom, m))
+                    requant_bits=9, changes_base=spec.changes_base), geom, m))
                 y_fused = np.asarray(execute_int8(
                     tiles, u_q, w_s, in_s, h_amax, spec=spec, geom=geom,
-                    hadamard_bits=9, interpret=True, fused=True))
+                    hadamard_bits=9, fused=True))
                 assert np.array_equal(y_fused, ref), (m, base)
                 mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
                 y_sh = np.asarray(execute_int8_sharded(
                     tiles, u_q, w_s, in_s, h_amax, spec=spec, geom=geom,
-                    mesh=mesh, hadamard_bits=9, interpret=True))
+                    mesh=mesh, hadamard_bits=9))
                 assert np.array_equal(y_sh, ref), (m, base)
         print("OK")
     """)
@@ -602,19 +591,17 @@ def test_tp_2d_sharded_parity_sweep():
                     if bits is not None:
                         _, amax = execute_int8(
                             tiles, u_q, w_s, in_s, spec=spec, geom=geom,
-                            hadamard_bits=bits, interpret=True,
+                            hadamard_bits=bits,
                             with_stats=True)
                         h_amax = amax.reshape(-1, 1)
                     ref = np.asarray(execute_int8(
                         tiles, u_q, w_s, in_s, h_amax, spec=spec,
-                        geom=geom, hadamard_bits=bits, fused=True,
-                        interpret=True))
+                        geom=geom, hadamard_bits=bits, fused=True))
                     ref_dyn = None
                     if bits is not None:
                         ref_dyn = np.asarray(execute_int8(
                             tiles, u_q, w_s, in_s, None, spec=spec,
-                            geom=geom, hadamard_bits=bits,
-                            interpret=True))
+                            geom=geom, hadamard_bits=bits))
                     for dd, dm in meshes:
                         mesh = Mesh(np.array(
                             jax.devices()[:dd * dm]).reshape(dd, dm),
@@ -622,7 +609,7 @@ def test_tp_2d_sharded_parity_sweep():
                         y = np.asarray(execute_int8_sharded(
                             tiles, u_q, w_s, in_s, h_amax, spec=spec,
                             geom=geom, mesh=mesh, hadamard_bits=bits,
-                            interpret=True, model_axis="model"))
+                            model_axis="model"))
                         assert np.array_equal(y, ref), \\
                             ("calibrated", m, base, bits, dd, dm,
                              np.abs(y - ref).max())
@@ -630,7 +617,7 @@ def test_tp_2d_sharded_parity_sweep():
                             yd = np.asarray(execute_int8_sharded(
                                 tiles, u_q, w_s, in_s, None, spec=spec,
                                 geom=geom, mesh=mesh, hadamard_bits=bits,
-                                interpret=True, model_axis="model"))
+                            model_axis="model"))
                             assert np.array_equal(yd, ref_dyn), \\
                                 ("dynamic", m, base, bits, dd, dm,
                                  np.abs(yd - ref_dyn).max())
@@ -673,24 +660,24 @@ def test_tp_f63_and_small_slab_regression():
             in_s = scales_from_abs_max(_tiles_abs_max(tiles, spec))
             _, amax = execute_int8(tiles, u_q, w_s, in_s, spec=spec,
                                    geom=geom, hadamard_bits=bits,
-                                   interpret=True, with_stats=True)
+                                   with_stats=True)
             h_amax = amax.reshape(-1, 1)
             ref = np.asarray(execute_int8(
                 tiles, u_q, w_s, in_s, h_amax, spec=spec, geom=geom,
-                hadamard_bits=bits, fused=True, interpret=True))
+                hadamard_bits=bits, fused=True))
             ref_dyn = np.asarray(execute_int8(
                 tiles, u_q, w_s, in_s, None, spec=spec, geom=geom,
-                hadamard_bits=bits, interpret=True))
+                hadamard_bits=bits))
             mesh = Mesh(np.array(jax.devices()[:dd * dm]).reshape(dd, dm),
                         ("data", "model"))
             y = np.asarray(execute_int8_sharded(
                 tiles, u_q, w_s, in_s, h_amax, spec=spec, geom=geom,
-                mesh=mesh, hadamard_bits=bits, interpret=True,
+                mesh=mesh, hadamard_bits=bits,
                 model_axis="model"))
             assert np.array_equal(y, ref), (m, base, bits, dd, dm)
             yd = np.asarray(execute_int8_sharded(
                 tiles, u_q, w_s, in_s, None, spec=spec, geom=geom,
-                mesh=mesh, hadamard_bits=bits, interpret=True,
+                mesh=mesh, hadamard_bits=bits,
                 model_axis="model"))
             assert np.array_equal(yd, ref_dyn), (m, base, bits, dd, dm)
         print("OK")

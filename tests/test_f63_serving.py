@@ -38,8 +38,7 @@ def _prepared(x, w, spec, bits):
     h_amax = None
     if bits is not None:
         _, amax = execute_int8(tiles, u_q, w_scales, in_scales, spec=spec,
-                               geom=geom, hadamard_bits=bits,
-                               interpret=True, with_stats=True)
+                               geom=geom, hadamard_bits=bits, with_stats=True)
         h_amax = amax.reshape(-1, 1)
     return tiles, geom, u_q, w_scales, in_scales, h_amax
 
@@ -54,7 +53,7 @@ def test_f63_fused_matches_staged(base, bits):
     x = jax.random.normal(KEY, (1, 12, 12, 4))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 4, 6)) * 0.2
     tiles, geom, u_q, w_s, in_s, h_amax = _prepared(x, w, spec, bits)
-    kw = dict(spec=spec, geom=geom, hadamard_bits=bits, interpret=True)
+    kw = dict(spec=spec, geom=geom, hadamard_bits=bits)
     y_staged = execute_int8(tiles, u_q, w_s, in_s, h_amax, fused=False,
                             **kw)
     y_fused = execute_int8(tiles, u_q, w_s, in_s, h_amax, fused=True, **kw)
@@ -71,11 +70,10 @@ def test_f63_dynamic_matches_calibrated_staged(base):
     spec = _spec(base, 9)
     x = jax.random.normal(KEY, (1, 12, 12, 4))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 4, 6)) * 0.2
-    y_dyn = winograd_conv2d_int8(x, w, spec, hadamard_bits=9, fused=False,
-                                 interpret=True)
+    y_dyn = winograd_conv2d_int8(x, w, spec, hadamard_bits=9, fused=False)
     tiles, geom, u_q, w_s, in_s, h_amax = _prepared(x, w, spec, 9)
     y_cal = execute_int8(tiles, u_q, w_s, in_s, h_amax, spec=spec,
-                         geom=geom, hadamard_bits=9, interpret=True,
+                         geom=geom, hadamard_bits=9,
                          fused=False)
     np.testing.assert_array_equal(np.asarray(y_dyn), np.asarray(y_cal))
 
@@ -91,14 +89,14 @@ def test_f63_one_xq_bitwise_across_modes():
     tiles, geom, u_q, w_s, in_s, h_amax = _prepared(x, w, spec, 9)
     mats = make_matrices(spec)
     y = execute_int8(tiles, u_q, w_s, in_s, h_amax, spec=spec, geom=geom,
-                     hadamard_bits=9, interpret=True, fused=True)
-    Xq = quantize_input(tiles, in_s, spec=spec, interpret=True)
+                     hadamard_bits=9, fused=True)
+    Xq = quantize_input(tiles, in_s, spec=spec)
     deq = in_s * w_s
     rq = jnp.maximum(h_amax, 1e-12) / qmax(9)
     ref = _reassemble(
         fused_gemm_output(Xq, u_q, deq, rq, mats.CinvT, mats.APT,
                           m=spec.m, requant_bits=9,
-                          changes_base=spec.changes_base, interpret=True),
+                          changes_base=spec.changes_base),
         geom, spec.m)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(ref))
 
@@ -110,14 +108,14 @@ def test_f63_hadamard_integer_domain_exact():
     x = jax.random.normal(KEY, (1, 12, 12, 4))
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 4, 6)) * 0.2
     tiles, geom, u_q, w_s, in_s, h_amax = _prepared(x, w, spec, 9)
-    Xq = quantize_input(tiles, in_s, spec=spec, interpret=True)
+    Xq = quantize_input(tiles, in_s, spec=spec)
     deq = in_s * w_s
-    H = wino_gemm(Xq, u_q, interpret=True)
+    H = wino_gemm(Xq, u_q)
     hf = H.astype(jnp.float32) * deq[:, :, None]
     s_h = jnp.maximum(h_amax.reshape(-1, 1, 1), 1e-12) / qmax(9)
     ref = jnp.clip(jnp.round(hf / s_h), -qmax(9),
                    qmax(9)).astype(jnp.int32)
-    out = wino_gemm(Xq, u_q, interpret=True, requant_bits=9, deq=deq,
+    out = wino_gemm(Xq, u_q, requant_bits=9, deq=deq,
                     rq=s_h[:, :, 0])
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 

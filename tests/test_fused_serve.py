@@ -37,10 +37,9 @@ def _staged_and_fused(x, w, spec, bits):
     h_amax = None
     if bits is not None:
         _, amax = execute_int8(tiles, u_q, w_scales, in_scales, spec=spec,
-                               geom=geom, hadamard_bits=bits,
-                               interpret=True, with_stats=True)
+                               geom=geom, hadamard_bits=bits, with_stats=True)
         h_amax = amax.reshape(-1, 1)
-    kw = dict(spec=spec, geom=geom, hadamard_bits=bits, interpret=True)
+    kw = dict(spec=spec, geom=geom, hadamard_bits=bits)
     y_staged = execute_int8(tiles, u_q, w_scales, in_scales, h_amax,
                             fused=False, **kw)
     y_fused = execute_int8(tiles, u_q, w_scales, in_scales, h_amax,
@@ -91,13 +90,13 @@ def test_gemm_requant_epilogue_exact_int(bits):
                            jnp.int8)
     deq = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (P, 1))) * 1e-3 \
         + 1e-5
-    H = wino_gemm(x, w, blocks=(8, 8, 8), interpret=True)
+    H = wino_gemm(x, w, blocks=(8, 8, 8))
     hf = H.astype(jnp.float32) * deq[:, :, None]
     amax = jnp.max(jnp.abs(hf), axis=(1, 2), keepdims=True)
     s_h = jnp.maximum(amax, 1e-12) / qmax(bits)
     ref = jnp.clip(jnp.round(hf / s_h), -qmax(bits),
                    qmax(bits)).astype(jnp.int32)
-    out = wino_gemm(x, w, blocks=(8, 8, 8), interpret=True,
+    out = wino_gemm(x, w, blocks=(8, 8, 8),
                     requant_bits=bits, deq=deq, rq=s_h[:, :, 0])
     assert out.dtype == jnp.int32
     assert np.abs(np.asarray(out)).max() <= qmax(bits)
@@ -108,7 +107,7 @@ def test_gemm_epilogue_requires_scales():
     x = jnp.zeros((4, 8, 8), jnp.int8)
     w = jnp.zeros((4, 8, 8), jnp.int8)
     with pytest.raises(ValueError):
-        wino_gemm(x, w, interpret=True, requant_bits=8)
+        wino_gemm(x, w, requant_bits=8)
 
 
 @pytest.mark.parametrize("base", ["canonical", "legendre"])
@@ -125,12 +124,11 @@ def test_fused_kernel_vs_staged_kernels_small_blocks(base, bits):
                              jnp.int8)
     deq = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (P, 1))) * 1e-3 \
         + 1e-5
-    H = wino_gemm(xq, u_q, interpret=True)
+    H = wino_gemm(xq, u_q)
     if bits is None:
         rq = jnp.ones_like(deq)
         ref = output_transform(H, deq, mats.CinvT, mats.APT, m=m,
-                               changes_base=spec.changes_base,
-                               interpret=True)
+                               changes_base=spec.changes_base)
     else:
         hf = H.astype(jnp.float32) * deq[:, :, None]
         amax = jnp.max(jnp.abs(hf), axis=(1, 2), keepdims=True)
@@ -139,13 +137,12 @@ def test_fused_kernel_vs_staged_kernels_small_blocks(base, bits):
                       qmax(bits)).astype(jnp.int32)
         rq = s_h[:, :, 0]
         ref = output_transform(Hq, rq, mats.CinvT, mats.APT, m=m,
-                               changes_base=spec.changes_base,
-                               interpret=True)
+                               changes_base=spec.changes_base)
     out = fused_gemm_output(xq, u_q, deq, rq, mats.CinvT, mats.APT, m=m,
                             requant_bits=bits,
                             changes_base=spec.changes_base,
-                            blocks=(8, 8, 8), interpret=True)
-    assert out.shape == (T, Co, m, m)
+                            blocks=(8, 8, 8))
+    assert out.shape == (m * m, T, Co)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 

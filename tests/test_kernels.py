@@ -1,5 +1,6 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode): shape/dtype
-sweeps with exact integer equality where the path is integer-exact."""
+"""Pallas kernels vs pure-jnp oracles (interpret mode on the CPU
+backend): shape/dtype sweeps with exact integer equality where the path
+is integer-exact."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +27,7 @@ def test_wino_gemm_exact(P, M, K, N, blocks):
     x = jax.random.randint(KEY, (P, M, K), -127, 128, jnp.int8)
     w = jax.random.randint(jax.random.PRNGKey(1), (P, K, N), -127, 128,
                            jnp.int8)
-    out = wino_gemm(x, w, blocks=blocks, interpret=True)
+    out = wino_gemm(x, w, blocks=blocks)
     ref = kref.wino_gemm_ref(x, w)
     assert out.dtype == jnp.int32
     assert (np.asarray(out) == np.asarray(ref)).all()
@@ -40,7 +41,7 @@ def test_wino_gemm_property(p, m, k, n):
     x = jax.random.randint(key, (p, m, k), -127, 128, jnp.int8)
     w = jax.random.randint(jax.random.fold_in(key, 1), (p, k, n),
                            -127, 128, jnp.int8)
-    out = wino_gemm(x, w, blocks=(16, 16, 16), interpret=True)
+    out = wino_gemm(x, w, blocks=(16, 16, 16))
     assert (np.asarray(out) == np.asarray(kref.wino_gemm_ref(x, w))).all()
 
 
@@ -51,7 +52,7 @@ def test_q8_matmul(M, K, N):
                             jnp.int8)
     sx = jnp.float32(0.013)
     sw = jax.random.uniform(jax.random.PRNGKey(3), (N,)) * 0.02 + 1e-4
-    out = q8_matmul(xq, wq, sx, sw, blocks=(32, 32, 32), interpret=True)
+    out = q8_matmul(xq, wq, sx, sw, blocks=(32, 32, 32))
     ref = kref.q8_matmul_ref(xq, wq, sx, sw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
@@ -62,15 +63,15 @@ def test_input_transform_kernel(base, T, C):
     spec = WinogradSpec(m=4, r=3, base=base, quant=QuantConfig.off())
     mats = make_matrices(spec)
     n = spec.n
-    tiles = jax.random.normal(KEY, (T, C, n, n), jnp.float32)
-    v = kref._sandwich(mats.BPT, kref._sandwich(mats.CinvT, tiles)) \
-        if spec.changes_base else kref._sandwich(mats.BT, tiles)
-    v = jnp.moveaxis(v.reshape(T, C, n * n), -1, 0)
+    tiles = jax.random.normal(KEY, (n * n, T, C), jnp.float32)
+    win = tiles.reshape(n, n, T, C)
+    v = kref._sandwich(mats.BPT, kref._sandwich(mats.CinvT, win)) \
+        if spec.changes_base else kref._sandwich(mats.BT, win)
+    v = v.reshape(n * n, T, C)
     sc = (jnp.max(jnp.abs(v), axis=(1, 2)) / 127.0 + 1e-9).reshape(-1, 1)
     bpt = mats.BPT if spec.changes_base else mats.BT
     out = input_transform(tiles, mats.CinvT, bpt, sc,
-                          changes_base=spec.changes_base, block=(8, 64),
-                          interpret=True)
+                          changes_base=spec.changes_base, block=(8, 64))
     ref = kref.input_transform_ref(tiles, mats.CinvT, bpt, sc,
                                    spec.changes_base)
     assert out.dtype == jnp.int8
@@ -92,8 +93,7 @@ def test_output_transform_kernel(base):
         + 1e-6
     apt = mats.APT if spec.changes_base else mats.AT
     out = output_transform(h, deq, mats.CinvT, apt, m=4,
-                           changes_base=spec.changes_base, block=(8, 16),
-                           interpret=True)
+                           changes_base=spec.changes_base, block=(8, 16))
     ref = kref.output_transform_ref(h, deq, mats.CinvT, apt, 4,
                                     spec.changes_base)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -107,7 +107,7 @@ def test_int8_conv_end_to_end(base):
     x = jax.random.normal(KEY, (2, 12, 12, 8))
     w = jax.random.normal(jax.random.PRNGKey(3), (3, 3, 8, 16)) * 0.2
     spec = WinogradSpec(m=4, r=3, base=base, quant=QuantConfig.off())
-    y = winograd_conv2d_int8(x, w, spec, interpret=True)
+    y = winograd_conv2d_int8(x, w, spec)
     ref = direct_conv2d(x, w, "same")
     assert y.shape == ref.shape
     rel = float(jnp.sqrt(jnp.mean((y - ref) ** 2)) /
@@ -118,7 +118,7 @@ def test_int8_conv_end_to_end(base):
 def test_q8_linear():
     x = jax.random.normal(KEY, (4, 10, 64))
     w = jax.random.normal(jax.random.PRNGKey(4), (64, 48))
-    y = q8_linear(x, w, interpret=True)
+    y = q8_linear(x, w)
     ref = x @ w
     rel = float(jnp.sqrt(jnp.mean((y - ref) ** 2)) /
                 jnp.sqrt(jnp.mean(ref ** 2)))

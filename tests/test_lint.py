@@ -89,10 +89,10 @@ def test_shard_map_wrapped_callable_is_tracked():
     # single-flavor call sites stay clean
     src_ok = textwrap.dedent("""
         import jax, numpy as np
-        from repro.distributed.sharding import shard_map_compat
+        from jax import shard_map
         def f(a):
             return a
-        g = shard_map_compat(f, None, in_specs=(), out_specs=())
+        g = shard_map(f, mesh=None, in_specs=(), out_specs=())
         g(jax.device_put(np.ones(3)))
         g(jax.device_put(np.zeros(3)))
     """)
