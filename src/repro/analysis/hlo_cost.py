@@ -24,7 +24,7 @@ import json
 import re
 from typing import Optional
 
-__all__ = ["analyze_hlo", "HloCost", "entry_boundary_bytes"]
+__all__ = ["analyze_hlo", "HloCost", "entry_boundary_bytes", "op_names"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -154,6 +154,23 @@ def _parse_instr(line: str) -> Optional[Instr]:
     attrs = line[close_idx + 1:]
     operands = re.findall(r"%([\w.\-]+)", operand_str)
     return Instr(name, type_str, op, operands, attrs, operand_str)
+
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def _op_name(attrs: str) -> str:
+    m = _OP_NAME_RE.search(attrs)
+    return m.group(1) if m else ""
+
+
+def op_names(text: str) -> dict[str, str]:
+    """Each instruction's ``op_name`` metadata ("" where it has none), by
+    instruction name, over every computation of an HLO module's text —
+    what names the instruction of a profiler's device event."""
+    return {ins.name: _op_name(ins.attrs)
+            for lines in _split_computations(text).values()
+            for ins in map(_parse_instr, lines) if ins is not None}
 
 
 _PASSTHROUGH = {"bitcast", "reshape", "copy", "transpose", "convert",
@@ -435,13 +452,12 @@ def attribute_hlo(text: str, top: int = 25,
                 out_b = _type_elems_bytes(ins.type_str)[1]
                 in_b = _fusion_in_bytes(parsed.get(mm.group(1), []) if mm
                                         else [], ins.operands, shapes)
-                meta = re.search(r'op_name="([^"]*)"', ins.attrs)
                 records.append({
                     "comp": cname, "op": op, "name": ins.name,
                     "type": ins.type_str[:48], "mult": mult,
                     "flops": inner_flops * mult,
                     "bytes": (in_b + out_b) * mult, "coll": 0.0,
-                    "meta": (meta.group(1) if meta else "")[-80:],
+                    "meta": _op_name(ins.attrs)[-80:],
                 })
                 continue
             is_coll = any(op == c or op == c + "-start"
@@ -451,7 +467,6 @@ def attribute_hlo(text: str, top: int = 25,
                        for o in ins.operands)
             if op in _FREE_OPS and not is_coll:
                 continue
-            meta = re.search(r'op_name="([^"]*)"', ins.attrs)
             flops = 0.0
             if op == "dot":
                 m2 = re.search(r"lhs_contracting_dims=\{([\d,]*)\}",
@@ -469,7 +484,7 @@ def attribute_hlo(text: str, top: int = 25,
                 "flops": flops * mult,
                 "bytes": (in_b + out_b) * mult,
                 "coll": out_b * mult if is_coll else 0.0,
-                "meta": (meta.group(1) if meta else "")[-80:],
+                "meta": _op_name(ins.attrs)[-80:],
             })
 
     # prime the per-computation flops cache via analyze_hlo's machinery

@@ -104,6 +104,7 @@ from typing import Iterable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.conv.packing import (PackedWinogradWeights, merge_abs_max,
                                 pack_weights, place_packed_state,
                                 scales_from_abs_max)
@@ -305,11 +306,13 @@ class ConvEngine:
         for g in geometries:
             g = tuple(int(d) for d in g)
             t0 = time.perf_counter()
-            # device_put, matching the serving loop's dispatch: a
-            # committed array keys a different jit-cache entry than an
-            # uncommitted one, and warmup must build the hot path's.
-            x = jax.device_put(jnp.zeros(g, jnp.float32))
-            jax.block_until_ready(forward(x))
+            with telemetry.setup_phase("warmup", bucket=g[0]):
+                # device_put, matching the serving loop's dispatch: a
+                # committed array keys a different jit-cache entry than
+                # an uncommitted one, and warmup must build the hot
+                # path's.
+                x = jax.device_put(jnp.zeros(g, jnp.float32))
+                jax.block_until_ready(forward(x))
             times[g] = time.perf_counter() - t0
         return times
 
@@ -378,7 +381,15 @@ class ConvEngine:
         packed state, the packed weights are authoritative and a
         caller-passed ``w`` is ignored — after updating model weights,
         re-run ``prepare``/``clear_packed`` so serving state tracks them.
+
+        Traced under ``jax.named_scope(layer)``, and inside it the stage
+        scopes of ``repro.telemetry``, so each XLA op's ``op_name`` names
+        its layer and stage.
         """
+        with jax.named_scope(layer):
+            return self._conv2d(x, w, layer, stride, flex, padding)
+
+    def _conv2d(self, x, w, layer, stride, flex, padding):
         pad = padding or self.padding
         pk = self.packed.get(layer)
         spec = self._layer_spec(layer)
@@ -398,7 +409,8 @@ class ConvEngine:
                 f"{backend!r} — packed state only serves winograd_int8")
 
         if backend == "direct":
-            return _direct(x, w, stride, pad)
+            with jax.named_scope(telemetry.DIRECT):
+                return _direct(x, w, stride, pad)
         if backend == "winograd_fp":
             return winograd_conv2d(x, w, self.fp_spec, mats=self.mats,
                                    flex=flex, padding=pad)
@@ -425,7 +437,8 @@ class ConvEngine:
                 # dynamic-requant layers run the staged slab with the
                 # plane abs-max assembled by one pmax — exactly the
                 # single-device dynamic derivation.
-                tiles = _extract(x, spec.m, spec.r, spec.n, pad)
+                with jax.named_scope(telemetry.EXTRACT):
+                    tiles = _extract(x, spec.m, spec.r, spec.n, pad)
                 geom = _geometry(x.shape, spec.m, spec.r, pad)
                 return execute_int8_sharded(
                     tiles, pk.u_q, pk.w_scales, pk.in_scales,
@@ -454,7 +467,8 @@ class ConvEngine:
             u_q, w_scales = pk.u_q, pk.w_scales
         else:
             u_q, w_scales = prepare_weights_int8(w, spec)
-        tiles = _extract(x, spec.m, spec.r, spec.n, pad)
+        with jax.named_scope(telemetry.EXTRACT):
+            tiles = _extract(x, spec.m, spec.r, spec.n, pad)
         geom = _geometry(x.shape, spec.m, spec.r, pad)
         amax = _tiles_abs_max(tiles, spec)
         self._amax[layer] = merge_abs_max(self._amax.get(layer), amax)
@@ -568,10 +582,11 @@ class ConvEngine:
     def prepare(self, named_weights: Iterable[tuple]) -> list[str]:
         """Pack every int8-routed layer. Items: (layer, w[, stride])."""
         packed = []
-        for item in named_weights:
-            layer, w, stride = item if len(item) == 3 else (*item, 1)
-            if self.prepare_layer(layer, w, stride=stride):
-                packed.append(layer)
+        with telemetry.setup_phase("pack"):
+            for item in named_weights:
+                layer, w, stride = item if len(item) == 3 else (*item, 1)
+                if self.prepare_layer(layer, w, stride=stride):
+                    packed.append(layer)
         return packed
 
     def clear_packed(self, calibrations: bool = False):
@@ -590,11 +605,12 @@ class ConvEngine:
         Run forwards eagerly inside the block (the engine folds concrete
         abs-maxima into running state, which a jit trace cannot do).
         """
-        self.begin_calibration()
-        try:
-            yield self
-        finally:
-            self.end_calibration()
+        with telemetry.setup_phase("calibrate"):
+            self.begin_calibration()
+            try:
+                yield self
+            finally:
+                self.end_calibration()
 
     def begin_calibration(self):
         self._calibrating = True

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import telemetry
 from repro.core.quantization import qmax
 from repro.kernels import backend
 from repro.kernels.backend import smem_spec
@@ -163,6 +164,7 @@ def fused_gemm_output(xq: jnp.ndarray, u_q: jnp.ndarray, deq: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((m * m, Tp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, bm, bn), jnp.int32)],
         interpret=backend.interpret_mode(),
+        name=telemetry.GEMM_OUTPUT,
     )(xp, wp, deq.reshape(-1), rq.reshape(-1), cinvt.reshape(-1),
       apt.reshape(-1))
     return out[:, :T, :N]

@@ -66,6 +66,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core.quantization import QuantConfig, qmax
 from repro.core.winograd import (WinogradMatrices, WinogradSpec,
                                  _extract_tiles_1d_axis, _pad_amounts,
@@ -233,7 +234,8 @@ def winograd_conv2d_int8(x: jnp.ndarray, w: Optional[jnp.ndarray],
         u_q, w_scales = prepare_weights_int8(w, spec)
     elif w_scales is None:
         raise ValueError("prepared u_q requires w_scales")
-    tiles = _extract(x, spec.m, spec.r, spec.n, padding)        # once
+    with jax.named_scope(telemetry.EXTRACT):
+        tiles = _extract(x, spec.m, spec.r, spec.n, padding)    # once
     geom = _geometry(x.shape, spec.m, spec.r, padding)
     if in_scales is None:
         in_scales = scales_from_abs_max(_tiles_abs_max(tiles, spec))
@@ -309,23 +311,26 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
     mats = make_matrices(spec)
     m = spec.m
 
-    Xq = quantize_input(tiles, in_scales, spec=spec)
+    with jax.named_scope(telemetry.INPUT_TRANSFORM):
+        Xq = quantize_input(tiles, in_scales, spec=spec)
     deq = in_scales * w_scales                       # (P, 1)
 
     use_fused = (fused and not with_stats
                  and (hadamard_bits is None or h_amax is not None))
     if use_fused:
-        if hadamard_bits is None:
-            rq = jnp.ones_like(deq)
-        else:
-            # Same scale formula as the staged requant below — keeping the
-            # fused and staged executions bit-identical.
-            rq = _hadamard_rq(h_amax, hadamard_bits)
-        y = fused_gemm_output(Xq, u_q, deq, rq, mats.CinvT, mats.APT,
-                              m=m, requant_bits=hadamard_bits,
-                              changes_base=spec.changes_base,
-                              blocks=blocks)
-        return _reassemble(y, geom, m)
+        with jax.named_scope(telemetry.GEMM_OUTPUT):
+            if hadamard_bits is None:
+                rq = jnp.ones_like(deq)
+            else:
+                # Same scale formula as the staged requant below — keeping
+                # the fused and staged executions bit-identical.
+                rq = _hadamard_rq(h_amax, hadamard_bits)
+            y = fused_gemm_output(Xq, u_q, deq, rq, mats.CinvT, mats.APT,
+                                  m=m, requant_bits=hadamard_bits,
+                                  changes_base=spec.changes_base,
+                                  blocks=blocks)
+        with jax.named_scope(telemetry.REASSEMBLE):
+            return _reassemble(y, geom, m)
 
     amax_h = None
     if (hadamard_bits is not None and h_amax is not None
@@ -354,9 +359,11 @@ def execute_int8(tiles: jnp.ndarray, u_q: jnp.ndarray,
                          qmax(hadamard_bits)).astype(jnp.int32)
             deq = s_h[:, :, 0]
 
-    y = output_transform(H, deq, mats.CinvT, mats.APT, m=m,
-                         changes_base=spec.changes_base)
-    out = _reassemble(y, geom, m)
+    with jax.named_scope(telemetry.OUTPUT_TRANSFORM):
+        y = output_transform(H, deq, mats.CinvT, mats.APT, m=m,
+                             changes_base=spec.changes_base)
+    with jax.named_scope(telemetry.REASSEMBLE):
+        out = _reassemble(y, geom, m)
     if with_stats:
         return out, amax_h[:, 0, 0]
     return out

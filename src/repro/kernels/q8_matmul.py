@@ -89,5 +89,6 @@ def q8_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, s_x: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=backend.interpret_mode(),
+        name="q8_matmul",
     )(xp, wp, sx, swp)
     return out[:M, :N]

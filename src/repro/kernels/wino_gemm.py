@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro import telemetry
 from repro.core.quantization import qmax
 from repro.kernels import backend
 from repro.kernels.backend import smem_spec
@@ -238,5 +239,6 @@ def wino_gemm(x: jnp.ndarray, w: jnp.ndarray,
         out_specs=pl.BlockSpec((1, bm, bn), lambda p, i, j, k: (p, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Mp, Np), jnp.int32),
         interpret=backend.interpret_mode(),
+        name=telemetry.GEMM,
     )(*operands)
     return out[:, :M, :N]

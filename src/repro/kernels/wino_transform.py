@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import telemetry
 from repro.kernels import backend
 from repro.kernels.backend import smem_spec
 
@@ -170,6 +171,7 @@ def input_transform(tiles: jnp.ndarray, cinvt: jnp.ndarray, bpt: jnp.ndarray,
         out_specs=pl.BlockSpec((P, bt, bc), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Tp, Cp), jnp.int8),
         interpret=backend.interpret_mode(),
+        name=telemetry.INPUT_TRANSFORM,
     )(tp, cinvt.reshape(-1), bpt.reshape(-1), pos_scale.reshape(-1))
     return out[:, :T, :C]
 
@@ -219,5 +221,6 @@ def output_transform(h: jnp.ndarray, pos_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((m * m, bt, bc), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct((m * m, Tp, Cp), jnp.float32),
         interpret=backend.interpret_mode(),
+        name=telemetry.OUTPUT_TRANSFORM,
     )(hp, pos_scale.reshape(-1), cinvt.reshape(-1), apt.reshape(-1))
     return out[:, :T, :C]

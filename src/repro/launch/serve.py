@@ -53,6 +53,7 @@ import numpy as np
 
 import jax
 
+from repro import telemetry
 from repro.checkpoint.checkpoint import restore, save
 from repro.conv import Plan, PlanEntry, build_plan
 from repro.core.quantization import QuantConfig
@@ -144,13 +145,15 @@ def make_served_engine(args, cfg, template):
     # free first: it defines which layers the restore template expects
     # packed, so the engine must know it before import (None for a
     # pre-plan checkpoint → pure policy routing, unchanged).
-    plan = Plan.from_checkpoint(args.ckpt_dir)
-    if plan is not None:
-        print(f"[plan] serving the checkpoint's plan: {plan.describe()}")
-    engine = RN.make_engine(cfg, backend="winograd_int8", mesh=mesh,
-                            model_axis=model_axis, plan=plan)
-    tree, _ = restore(args.ckpt_dir, template, shardings=shardings)
-    engine.import_state(tree)
+    with telemetry.setup_phase("restore"):
+        plan = Plan.from_checkpoint(args.ckpt_dir)
+        if plan is not None:
+            print(f"[plan] serving the checkpoint's plan: "
+                  f"{plan.describe()}")
+        engine = RN.make_engine(cfg, backend="winograd_int8", mesh=mesh,
+                                model_axis=model_axis, plan=plan)
+        tree, _ = restore(args.ckpt_dir, template, shardings=shardings)
+        engine.import_state(tree)
     return engine
 
 

@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.conv import ConvEngine, ConvPolicy, LayerGeom
 from repro.core.quantization import QuantConfig
 from repro.core.winograd import WinogradSpec, flex_init
@@ -211,8 +212,11 @@ def serving_forward(params, state, cfg: ResNetConfig, engine: ConvEngine):
     each call to this factory is a fresh ``jax.jit`` with an empty
     compile cache, so re-wrapping would re-compile (and break the
     serving loop's zero-recompile accounting)."""
-    return jax.jit(lambda im: forward(params, state, im, cfg,
-                                      training=False, engine=engine)[0])
+    def serve_resnet(im):
+        return forward(params, state, im, cfg, training=False,
+                       engine=engine)[0]
+
+    return jax.jit(serve_resnet)
 
 
 def conv_layers(params, cfg: ResNetConfig):
@@ -254,7 +258,9 @@ def forward(params, state, images, cfg: ResNetConfig, training: bool = False,
     """images: (B, 32, 32, 3) → logits (B, classes), new_state.
 
     ``engine`` carries prepared/calibrated serving state; omitted, a
-    stateless engine is built from the config (training path).
+    stateless engine is built from the config (training path). Batch norm,
+    the residual add and the head are traced under the stage scopes of
+    ``repro.telemetry``, beside the engine's per-layer conv scopes.
     """
     if engine is None:
         engine = make_engine(cfg)
@@ -263,31 +269,37 @@ def forward(params, state, images, cfg: ResNetConfig, training: bool = False,
     new_state = {"blocks": {}}
 
     x = engine.conv2d(images, params["stem"], layer="stem", flex=flex)
-    x, new_state["bn_stem"] = _bn(x, params["bn_stem"], state["bn_stem"],
-                                  training, mom)
-    x = jax.nn.relu(x)
+    with jax.named_scope(f"stem/{telemetry.BN}"):
+        x, new_state["bn_stem"] = _bn(x, params["bn_stem"],
+                                      state["bn_stem"], training, mom)
+        x = jax.nn.relu(x)
 
     for nm, cin, cout, stride in _iter_blocks(cfg):
         p, st = params["blocks"][nm], state["blocks"][nm]
         ns = {}
         h = engine.conv2d(x, p["conv1"], layer=f"{nm}.conv1", stride=stride,
                           flex=flex)
-        h, ns["bn1"] = _bn(h, p["bn1"], st["bn1"], training, mom)
-        h = jax.nn.relu(h)
+        with jax.named_scope(f"{nm}.conv1/{telemetry.BN}"):
+            h, ns["bn1"] = _bn(h, p["bn1"], st["bn1"], training, mom)
+            h = jax.nn.relu(h)
         h = engine.conv2d(h, p["conv2"], layer=f"{nm}.conv2", flex=flex)
-        h, ns["bn2"] = _bn(h, p["bn2"], st["bn2"], training, mom)
+        with jax.named_scope(f"{nm}.conv2/{telemetry.BN}"):
+            h, ns["bn2"] = _bn(h, p["bn2"], st["bn2"], training, mom)
         if "proj" in p:
             sc = engine.conv2d(x, p["proj"], layer=f"{nm}.proj",
                                stride=stride, flex=flex)
-            sc, ns["bn_proj"] = _bn(sc, p["bn_proj"], st["bn_proj"],
-                                    training, mom)
+            with jax.named_scope(f"{nm}.proj/{telemetry.BN}"):
+                sc, ns["bn_proj"] = _bn(sc, p["bn_proj"], st["bn_proj"],
+                                        training, mom)
         else:
             sc = x
-        x = jax.nn.relu(h + sc)
+        with jax.named_scope(f"{nm}/{telemetry.RELU_ADD}"):
+            x = jax.nn.relu(h + sc)
         new_state["blocks"][nm] = ns
 
-    x = jnp.mean(x, axis=(1, 2))
-    logits = x @ params["head"] + params["head_b"]
+    with jax.named_scope(telemetry.HEAD):
+        x = jnp.mean(x, axis=(1, 2))
+        logits = x @ params["head"] + params["head_b"]
     return logits, new_state
 
 

@@ -26,6 +26,10 @@ The structure (one dispatcher thread, depth-2 pipeline):
   per client.
 * **Drain** — ``shutdown(drain=True)`` stops intake, flushes the queue
   and the pending ring, completes every future, and joins the thread.
+* **Tracing** — each stage of each batch on the dispatcher thread is a
+  ``repro.serving.*`` profiler span (``repro.telemetry``) carrying the
+  batch's sequence number, which its records carry too; ``counters()``
+  counts batches, rows dispatched and padded, and requests delivered.
 
 The loop is model-agnostic: ``forward`` is any callable mapping a
 ``(B, *input_shape)`` array to per-row outputs (rows independent — the
@@ -43,7 +47,9 @@ from concurrent.futures import Future
 from typing import Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import telemetry
 from repro.serving.buckets import (DEFAULT_BUCKETS, bucket_for, device_put,
                                    pad_batch, validate_buckets)
 
@@ -92,6 +98,7 @@ class RequestRecord:
     t_done: float
     batch_n: int                 # real requests in the dispatched batch
     bucket: int                  # geometry it was padded into
+    batch: int                   # sequence number of its batch
 
     @property
     def latency_s(self) -> float:
@@ -106,6 +113,7 @@ class BatchRecord:
     t_open: float                # first request dequeued
     t_dispatch: float
     t_done: float
+    batch: int                   # sequence number, as in its spans
 
 
 @dataclasses.dataclass
@@ -119,6 +127,7 @@ class _Request:
 
 @dataclasses.dataclass
 class _InFlight:
+    batch: int
     requests: list
     y: object                    # dispatched (possibly async) result
     t_open: float
@@ -157,6 +166,9 @@ class ServingLoop:
         self._next_rid = 0
         self._outstanding = 0        # accepted but not yet delivered
         self._warm_cache: Optional[int] = None
+        self._counters = dict(batches=0, rows_dispatched=0, rows_padded=0,
+                              requests_delivered=0)
+        telemetry.install()
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -174,8 +186,9 @@ class ServingLoop:
                     # Through device_put, same as _dispatch: a raw numpy
                     # argument keys a different jit-cache entry, and
                     # warmup must compile the hot path's entry.
-                    _block(self.forward(device_put(
-                        np.zeros(g, np.float32))))
+                    with telemetry.setup_phase("warmup", bucket=g[0]):
+                        _block(self.forward(device_put(
+                            np.zeros(g, np.float32))))
                     self.warmup_times[g] = time.perf_counter() - t0
         self._warm_cache = jit_cache_size(self.forward)
         self._accepting = True
@@ -193,6 +206,13 @@ class ServingLoop:
         if cur is None or self._warm_cache is None:
             return None
         return cur - self._warm_cache
+
+    def counters(self) -> dict:
+        """A copy of the loop's counters: ``batches``, ``rows_dispatched``
+        (bucket rows sent to the device), ``rows_padded`` (of those, the
+        padding) and ``requests_delivered``. The process-wide gc and
+        compile counters are ``repro.telemetry.snapshot()``."""
+        return dict(self._counters)
 
     def submit(self, x: np.ndarray, client: Optional[str] = None) -> Future:
         """Enqueue one request (shape ``input_shape``); the Future
@@ -260,52 +280,65 @@ class ServingLoop:
         """Pull requests until the largest bucket is full or the batch
         deadline (``max_wait_ms`` after the batch opened) passes."""
         cfg = self.config
-        t_open = time.perf_counter()
-        deadline = t_open + cfg.max_wait_ms / 1e3
-        batch = [first]
-        while len(batch) < cfg.max_batch:
-            remain = deadline - time.perf_counter()
-            if remain <= 0:
-                break
-            try:
-                item = self._queue.get(timeout=remain)
-            except _queue.Empty:
-                break
-            if item is _SENTINEL:
-                self._stopping = True
-                break
-            batch.append(item)
+        with TraceAnnotation(telemetry.SERVING_COALESCE,
+                             batch=self._counters["batches"]) as span:
+            t_open = time.perf_counter()
+            deadline = t_open + cfg.max_wait_ms / 1e3
+            batch = [first]
+            while len(batch) < cfg.max_batch:
+                remain = deadline - time.perf_counter()
+                if remain <= 0:
+                    break
+                try:
+                    item = self._queue.get(timeout=remain)
+                except _queue.Empty:
+                    break
+                if item is _SENTINEL:
+                    self._stopping = True
+                    break
+                batch.append(item)
+            span.set_metadata(n=len(batch),
+                              bucket=bucket_for(len(batch), cfg.buckets))
         return batch, t_open
 
     def _dispatch(self, batch: list, t_open: float):
-        bucket = bucket_for(len(batch), self.config.buckets)
-        x = pad_batch(np.stack([r.x for r in batch]), bucket)
-        x = device_put(x)                        # host→device, async
-        y = self.forward(x)                      # async dispatch
-        self._pending.append(_InFlight(batch, y, t_open,
+        c = self._counters
+        n, k = len(batch), c["batches"]
+        bucket = bucket_for(n, self.config.buckets)
+        args = dict(batch=k, n=n, bucket=bucket)
+        with TraceAnnotation(telemetry.SERVING_PAD, **args):
+            x = pad_batch(np.stack([r.x for r in batch]), bucket)
+        with TraceAnnotation(telemetry.SERVING_PUT, **args):
+            x = device_put(x)                    # host→device, async
+        with TraceAnnotation(telemetry.SERVING_DISPATCH, **args):
+            y = self.forward(x)                  # async dispatch
+        c["batches"] += 1
+        c["rows_dispatched"] += bucket
+        c["rows_padded"] += bucket - n
+        self._pending.append(_InFlight(k, batch, y, t_open,
                                        time.perf_counter(), bucket))
 
     def _deliver(self, inflight: _InFlight):
-        y = np.asarray(_block(inflight.y))
-        t_done = time.perf_counter()
         n = len(inflight.requests)
-        self.batches.append(BatchRecord(n, inflight.bucket, inflight.t_open,
-                                        inflight.t_dispatch, t_done))
-        for i, req in enumerate(inflight.requests):
-            self.records.append(RequestRecord(
-                req.rid, req.client, req.t_submit, inflight.t_dispatch,
-                t_done, n, inflight.bucket))
-            req.future.set_result(y[i])
+        args = dict(batch=inflight.batch, n=n, bucket=inflight.bucket)
+        with TraceAnnotation(telemetry.SERVING_BLOCK, **args):
+            _block(inflight.y)
+        with TraceAnnotation(telemetry.SERVING_DELIVER, **args):
+            y = np.asarray(inflight.y)
+            t_done = time.perf_counter()
+            self.batches.append(BatchRecord(
+                n, inflight.bucket, inflight.t_open, inflight.t_dispatch,
+                t_done, inflight.batch))
+            for i, req in enumerate(inflight.requests):
+                self.records.append(RequestRecord(
+                    req.rid, req.client, req.t_submit, inflight.t_dispatch,
+                    t_done, n, inflight.bucket, inflight.batch))
+                req.future.set_result(y[i])
+            self._counters["requests_delivered"] += n
         with self._lock:
             self._outstanding -= n
 
     # -- reporting ----------------------------------------------------------
-
-    def padding_fraction(self) -> float:
-        """Fraction of dispatched rows that were padding."""
-        rows = sum(b.bucket for b in self.batches)
-        real = sum(b.n for b in self.batches)
-        return 0.0 if rows == 0 else 1.0 - real / rows
 
     def busy_fraction(self, wall_s: float) -> float:
         """Approximate device-busy fraction over ``wall_s`` — batch

@@ -1,8 +1,8 @@
 """Online serving front-end tests: bucket helpers, the bitwise
 bucketed-padding parity contract on a calibrated int8 conv engine, the
 continuous-batching queue semantics (max-wait flush, max-batch cap,
-per-client ordering, graceful drain), and the warmup / zero-recompile
-instrumentation."""
+per-client ordering, graceful drain), the loop's counters and batch
+numbers, and the warmup / zero-recompile instrumentation."""
 import threading
 import time
 
@@ -163,6 +163,28 @@ def test_max_batch_caps_coalescing():
     assert max(s[0] for s in fwd.shapes) <= 4
     assert sum(b.n for b in loop.batches) == 11
     assert any(b.n > 1 for b in loop.batches)  # it did coalesce
+
+
+def test_counters_add_up_and_records_carry_their_batch():
+    """Every dispatched row is a delivered request or padding, and every
+    record names its batch: the sequence number its spans carry."""
+    fwd = FakeForward(delay_s=0.01)
+    loop = _loop(fwd, buckets=(4,), max_wait_ms=20.0).start()
+    futs = [loop.submit(np.zeros((4,), np.float32)) for _ in range(5)]
+    for f in futs:
+        f.result(timeout=30)
+    loop.shutdown()
+    c = loop.counters()
+    assert c["requests_delivered"] == 5 and c["rows_padded"] >= 3
+    assert c["rows_dispatched"] == c["rows_padded"] + c["requests_delivered"]
+    assert c["batches"] == len(loop.batches)
+    assert c["rows_dispatched"] == sum(b.bucket for b in loop.batches)
+    assert [b.batch for b in loop.batches] == list(range(c["batches"]))
+    by_batch = {b.batch: b for b in loop.batches}
+    assert sorted(r.rid for r in loop.records) == list(range(5))
+    for r in loop.records:
+        assert (r.batch_n, r.bucket) == (by_batch[r.batch].n,
+                                         by_batch[r.batch].bucket)
 
 
 def test_completion_in_submission_order_per_client():
