@@ -2,7 +2,7 @@
 convolution (the inference path; QAT uses the fake-quant path in core/).
 
 Staged pipeline (NHWC):
-    extract tiles (XLA gather)                    → (n², T, Cin) fp
+    extract tiles (XLA slices + transpose)        → (n², T, Cin) fp
     kernels.input_transform   (fused, 1 HBM pass) → (n², T, Cin) int8
     kernels.wino_gemm         (MXU int8 GEMMs)    → (n², T, Cout) int32
     [optional Hadamard requant to 8/9 bits — the paper's knob; with
@@ -65,12 +65,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro import telemetry
 from repro.core.quantization import QuantConfig, qmax
 from repro.core.winograd import (WinogradMatrices, WinogradSpec,
-                                 _extract_tiles_1d_axis, _pad_amounts,
-                                 make_matrices, transform_weights_2d)
+                                 _pad_amounts, make_matrices,
+                                 transform_weights_2d)
 from repro.kernels import backend
 from repro.kernels import ref as kref
 from repro.kernels.fused_serve import fused_gemm_output
@@ -90,18 +91,40 @@ def _geometry(x_shape, m: int, r: int, padding: str):
     return (N, nt_h, nt_w, Ho, Wo)
 
 
+def _windows(x: jnp.ndarray, axis: int, m: int, n: int, nt: int
+             ) -> jnp.ndarray:
+    """Length-n windows at stride m along ``axis`` → (..., nt, n, ...).
+
+    ``x`` holds ``(nt + q - 1)·m`` rows on ``axis``, q = ⌈n/m⌉. Window i
+    joins the m-row blocks i … i + q - 1, cut to n rows: unit-stride
+    slices and one concatenate. (On a TPU v5e a gather compiles to a loop
+    of dynamic slices, and slices at stride m hung the served program.)
+    """
+    q = -(-n // m)
+    xb = x.reshape(x.shape[:axis] + (nt + q - 1, m) + x.shape[axis + 1:])
+    parts = [lax.slice_in_dim(lax.slice_in_dim(xb, k, k + nt, axis=axis),
+                              0, min(m, n - k * m), axis=axis + 1)
+             for k in range(q)]
+    return jnp.concatenate(parts, axis=axis + 1)
+
+
 @functools.partial(jax.jit, static_argnames=("m", "r", "n", "padding"))
 def _extract(x: jnp.ndarray, m: int, r: int, n: int, padding: str):
-    """(N,H,W,C) → (n², T, C) overlapping tiles, one fused call."""
+    """(N,H,W,C) → (n², T, C) overlapping tiles, one fused call.
+
+    Windows along H, then W (``_windows``), then one transpose puts the
+    window axes first: plane ``p = a·n + b``, tiles in (N, tile row,
+    tile column) order.
+    """
     N, H, W, C = x.shape
-    lo_h, hi_h, nt_h, _ = _pad_amounts(H, m, r, padding)
-    lo_w, hi_w, nt_w, _ = _pad_amounts(W, m, r, padding)
-    xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
-    t = _extract_tiles_1d_axis(xp, xp.shape[1], m, n, nt_h, axis=1)
-    t = _extract_tiles_1d_axis(t, t.shape[3], m, n, nt_w, axis=3)
+    q = -(-n // m)
+    lo_h, _, nt_h, _ = _pad_amounts(H, m, r, padding)
+    lo_w, _, nt_w, _ = _pad_amounts(W, m, r, padding)
+    xp = jnp.pad(x, ((0, 0), (lo_h, (nt_h + q - 1) * m - H - lo_h),
+                     (lo_w, (nt_w + q - 1) * m - W - lo_w), (0, 0)))
+    t = _windows(_windows(xp, 1, m, n, nt_h), 3, m, n, nt_w)
     t = jnp.transpose(t, (2, 4, 0, 1, 3, 5))        # (n,n,N,th,tw,C)
-    T = N * nt_h * nt_w
-    return t.reshape(n * n, T, C)
+    return t.reshape(n * n, N * nt_h * nt_w, C)
 
 
 def _reassemble(y: jnp.ndarray, geom, m: int) -> jnp.ndarray:

@@ -6,8 +6,10 @@ channels on the 128 lanes, tiles on the sublanes — and the n×n tile
 window on the leading axis, position-major (``p = a·n + b``). Each
 sandwich term is then a scalar times one ``(bt, bc)`` plane: pure VPU
 multiply-adds, with no minor-axis reshape for Mosaic to lay out. The
-spatial permutation between NHWC and this layout is XLA data movement
-in ``kernels.ops`` (``_extract`` / ``_reassemble``).
+move between NHWC and this layout is XLA data movement in
+``kernels.ops``: ``_extract`` cuts the padded activation into windows
+with slices and transposes them into the n² planes, ``_reassemble``
+transposes the m² output planes back.
 
 Input transform (fused, one HBM round-trip):
     tiles (n², T, C) fp32  →  C⁻ᵀ·X·C⁻¹ → B_Cᵀ·(·)·B_C → scale→round→clip

@@ -52,7 +52,7 @@ SETUP_PHASES = ("pack", "calibrate", "restore", "warmup")
 # ``ConvEngine.conv2d`` opens a scope named after the layer; inside it each
 # stage below. Every Pallas kernel is named after its stage.
 
-EXTRACT = "wino_extract"                   # tile gather and its pad
+EXTRACT = "wino_extract"                   # pad, tile windows, transpose
 INPUT_TRANSFORM = "wino_input_transform"   # int8 input transform + quantize
 GEMM_OUTPUT = "wino_gemm_output"           # fused GEMM + requant + output
 GEMM = "wino_gemm"                         # staged GEMM (+ requant)
