@@ -1,7 +1,7 @@
-"""The control: the plain reference at 4 bits (``reference.py``, ``bits=4``)
-put in the served program's place, driven through the whole run of a cell,
-so that its answers are judged by the same checks and limits. Its runs
-have to come out not correct.
+"""The control: the plain reference at 4 bits (the architecture module's
+``forward`` with ``bits=4``) put in the served program's place, driven
+through the whole run of a cell, so that its answers are judged by the
+same checks and limits. Its runs have to come out not correct.
 
     python chipbench/control.py --workload f23-offline-b256 \
         --seeds 11,12,13 --seconds 3
@@ -31,14 +31,12 @@ def reference_system(bits=None, fault=None):
     import jax
     from jax.profiler import TraceAnnotation
 
-    from chipbench import reference
-
     class ReferenceSystem:
         engine = None
 
-        def __init__(self, cfg, params, state, calibration, ckpt, log):
+        def __init__(self, arch, cfg, params, state, calibration, ckpt, log):
             def fwd(x):
-                y = reference.forward(cfg, params, state, x, bits)
+                y = arch.forward(cfg, params, state, x, bits)
                 return y if fault is None else fault(y)
             self.jitted = jax.jit(fwd)
 
@@ -63,10 +61,10 @@ def main(argv=None) -> int:
     from chipbench.run import require_chips
 
     manifest = harness.load_manifest(ROOT / "BENCHMARK.json")
-    cell, cfg, mix = harness.resolve(manifest, args.workload)
+    cell, arch, cfg, mix = harness.resolve(manifest, args.workload)
     devices = require_chips(cell["chips"])
     for seed in (int(s) for s in args.seeds.split(",")):
-        out = harness.run(manifest, cell, cfg, mix, seed, args.seconds,
+        out = harness.run(manifest, cell, arch, cfg, mix, seed, args.seconds,
                           False, time.perf_counter(),
                           reference_system(bits=4), devices)
         print(json.dumps({"seed": seed, "correct": out["correct"],

@@ -1,6 +1,7 @@
 """Operations and bytes, worked out from shapes: the model's operations per
-image, and the least time of each Mosaic kernel call as the trace names
-its operands. The peaks come from ``peaks.json``.
+image (from its architecture module's convolutions and head), and the
+least time of each Mosaic kernel call as the trace names its operands. The
+peaks come from ``peaks.json``.
 """
 from __future__ import annotations
 
@@ -8,8 +9,6 @@ import json
 import math
 import re
 from pathlib import Path
-
-from chipbench.weights import blocks
 
 _PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -27,26 +26,14 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def conv_layers(cfg: dict):
-    """``(name, H_out, W_out, k, Cin, Cout, stride)`` of every convolution."""
-    h, w, c = cfg["image_shape"]
-    yield "stem", h, w, 3, c, cfg["widths"][0], 1
-    for name, cin, cout, stride in blocks(cfg):
-        ho, wo = -(-h // stride), -(-w // stride)
-        yield f"{name}.conv1", ho, wo, 3, cin, cout, stride
-        yield f"{name}.conv2", ho, wo, 3, cout, cout, 1
-        if stride != 1 or cin != cout:
-            yield f"{name}.proj", ho, wo, 1, cin, cout, stride
-        h, w = ho, wo
-
-
-def model_ops_per_image(cfg: dict) -> int:
-    """2 x the multiply-adds of every convolution as a direct convolution,
-    plus the linear head. Winograd's saved multiplications count as the
+def model_ops_per_image(arch, cfg: dict) -> int:
+    """2 x the multiply-adds of every convolution (``arch.conv_layers``) as
+    a direct convolution, plus the linear head (its weight, ``head`` in
+    ``arch.shapes``). Winograd's saved multiplications count as the
     model's, as is usual."""
     convs = sum(2 * ho * wo * k * k * cin * cout
-                for _, ho, wo, k, cin, cout, _ in conv_layers(cfg))
-    return convs + 2 * cfg["widths"][-1] * cfg["num_classes"]
+                for _, ho, wo, k, cin, cout, _ in arch.conv_layers(cfg))
+    return convs + 2 * math.prod(arch.shapes(cfg)[0]["head"])
 
 
 def parse_shapes(text: str) -> list[tuple[str, tuple[int, ...]]]:

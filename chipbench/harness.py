@@ -33,10 +33,50 @@ def load_manifest(path: Path) -> dict:
     return json.loads(Path(path).read_text())
 
 
+#: Keys of a configuration file that every architecture shares: what it is
+#: and where it comes from, how it was cut from its source (the keys it
+#: changed, the sizes it assumed, the deployment it stands for), its
+#: images, the Winograd tile and bits it is served with, and how its
+#: answers are judged. Each architecture module names the keys of its own
+#: in ``KEYS``.
+COMMON_KEYS = frozenset({"model", "source", "arch", "reduced", "assumed",
+                         "deployment", "params", "image_shape",
+                         "num_classes", "winograd", "bits", "calibration",
+                         "limits", "err_level"})
+
+
+def architecture(name: str, arch_dir: Path = ROOT / "archs"):
+    """The architecture module ``<arch_dir>/<name, - as _>.py``: all the
+    benchmark knows about one network. A name with no module is an error,
+    never a default."""
+    path = Path(arch_dir) / f"{name.replace('-', '_')}.py"
+    if not path.is_file():
+        known = sorted(q.stem.replace("_", "-")
+                       for q in Path(arch_dir).glob("*.py"))
+        raise ValueError(f"no architecture module for arch {name!r} "
+                         f"({path.name} in {arch_dir}); known: {known}")
+    return _load(path, "arch")
+
+
+def _load(path: Path, kind: str):
+    """The module in the file ``path``, by its path: a data directory, not
+    a package."""
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def resolve(manifest: dict, workload: str, root: Path = ROOT.parent,
-            traffic_dir: Path = ROOT / "traffic"):
-    """The cell, its configuration (the manifest's ``file``, relative to
-    ``root``) and its traffic mix (``<traffic_dir>/<name>.json``)."""
+            traffic_dir: Path = ROOT / "traffic",
+            arch_dir: Path = ROOT / "archs"):
+    """The cell, its architecture module (the configuration's ``arch``,
+    from ``arch_dir``), its configuration (the manifest's ``file``,
+    relative to ``root``) and its traffic mix (``<traffic_dir>/<name>.json``).
+    A configuration that names no architecture, one with no module, or a
+    key that neither the module nor ``COMMON_KEYS`` knows is refused here,
+    before anything is built."""
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; known: "
@@ -44,8 +84,15 @@ def resolve(manifest: dict, workload: str, root: Path = ROOT.parent,
     cell = cells[workload]
     conf = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     cfg = json.loads((root / conf["file"]).read_text())
+    if "arch" not in cfg:
+        raise ValueError(f"{conf['file']} names no 'arch'")
+    arch = architecture(cfg["arch"], arch_dir)
+    unknown = set(cfg) - COMMON_KEYS - set(arch.KEYS)
+    if unknown:
+        raise ValueError(f"{conf['file']}: keys {sorted(unknown)} are not "
+                         f"read by arch {cfg['arch']!r}")
     mix = traffic.load(traffic_dir / f"{cell['traffic']}.json")
-    return cell, cfg, mix
+    return cell, arch, cfg, mix
 
 
 def metrics_for(manifest: dict, cell: dict, trace_on: bool) -> list[dict]:
@@ -57,12 +104,7 @@ def metrics_for(manifest: dict, cell: dict, trace_on: bool) -> list[dict]:
 
 def reader(name: str):
     """``metrics/<name>.py``'s ``read(ctx)``."""
-    path = ROOT / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(ROOT / "metrics" / f"{name}.py", "metric").read
 
 
 class Profiler(threading.Thread):
@@ -170,7 +212,8 @@ def error_profile(served: np.ndarray, ref: np.ndarray) -> str:
                         for lv in (0.1, 0.15, 0.2, 0.25, 0.3)))
 
 
-def _check(cfg, params, state, pool, answers, compiles_in_window, failed):
+def _check(arch, cfg, params, state, pool, answers, compiles_in_window,
+           failed):
     """Every delivered answer against the reference of its image, each
     number the configuration sets a limit on beside it, with compiles
     inside the window and failed requests (limits 0). A number that
@@ -182,7 +225,7 @@ def _check(cfg, params, state, pool, answers, compiles_in_window, failed):
         idx = np.array([i for i, _ in answers])
         uniq, inv = np.unique(idx, return_inverse=True)
         t0 = time.perf_counter()
-        ref = reference.logits(cfg, params, state, pool[uniq])[inv]
+        ref = reference.logits(arch, cfg, params, state, pool[uniq])[inv]
         log(f"reference over {len(uniq)} distinct images "
             f"({len(answers)} answers): {time.perf_counter() - t0:.2f}s")
         served = served.astype(np.float64)
@@ -204,15 +247,16 @@ class Server:
     the mix's buckets warmed up. ``window`` then serves the measured
     window."""
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, system_factory):
+    def __init__(self, arch, cfg: dict, mix: dict, seed: int,
+                 system_factory):
         from repro.serving import ServeConfig, ServingLoop
         self.cfg, self.mix, self.seed = cfg, mix, seed
         t0 = time.perf_counter()
-        self.params, self.state = weights.make_weights(cfg, seed)
+        self.params, self.state = weights.make_weights(arch, cfg, seed)
         log(f"weights {time.perf_counter() - t0:.3f}s")
         with tempfile.TemporaryDirectory(prefix="chipbench_ckpt_") as ckpt:
             self.system = system_factory(
-                cfg, self.params, self.state,
+                arch, cfg, self.params, self.state,
                 weights.calibration_images(cfg, seed), ckpt, log)
         self.pool = weights.images(cfg, seed, mix["pool"])
         self.loop = ServingLoop(
@@ -284,11 +328,11 @@ def describe(w) -> str:
             f"{w.failed} failed; " + w.gc.describe())
 
 
-def run(manifest: dict, cell: dict, cfg: dict, mix: dict, seed: int,
+def run(manifest: dict, cell: dict, arch, cfg: dict, mix: dict, seed: int,
         seconds: float, trace_on: bool, t_start: float, system_factory,
         devices) -> dict:
     """One run of ``cell``; returns the result line's object."""
-    server = Server(cfg, mix, seed, system_factory)
+    server = Server(arch, cfg, mix, seed, system_factory)
     warm_compiles = server.system.compiles()
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f}s (process start to window)")
@@ -314,7 +358,7 @@ def run(manifest: dict, cell: dict, cfg: dict, mix: dict, seed: int,
         out_extra["breakdown"] = red.breakdown()
         t_a, t_b = prof.span
         ctx = SimpleNamespace(
-            cfg=cfg, mix=mix, cell=cell, chips=len(devices),
+            arch=arch, cfg=cfg, mix=mix, cell=cell, chips=len(devices),
             peaks=costs.peaks(devices[0].device_kind), trace=red, window=w,
             log=log, images_traced=sum(b.n for b in w.batches
                                        if t_a <= b.t_done <= t_b))
@@ -326,7 +370,7 @@ def run(manifest: dict, cell: dict, cfg: dict, mix: dict, seed: int,
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
 
-    ok, checks = _check(cfg, params, state, pool, w.answers,
+    ok, checks = _check(arch, cfg, params, state, pool, w.answers,
                         compiles_in_window, w.failed)
     for k, c in checks.items():
         print(f"check {k}: {c['value']} (limit {c['limit']})",

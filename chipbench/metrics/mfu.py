@@ -9,5 +9,5 @@ def read(ctx):
     if not ctx.images_traced:
         return None
     rate = ctx.images_traced / ctx.trace.window_s
-    return 100.0 * costs.model_ops_per_image(ctx.cfg) * rate / (
+    return 100.0 * costs.model_ops_per_image(ctx.arch, ctx.cfg) * rate / (
         ctx.chips * ctx.peaks["int8_ops_per_s"])

@@ -1,11 +1,11 @@
-"""The plain reference: ResNet-18 inference in ``jax.numpy`` and float32.
+"""The plain reference: inference in ``jax.numpy`` and float32.
 
-Direct convolutions at ``highest`` matmul precision (on a TPU a float32
-matmul otherwise runs in one bf16 pass), batch norm from running
-statistics, ReLU, the residual adds, global average pooling and the linear
-head. No Winograd transform, no quantisation, no kernels, no batching
-logic. It imports nothing of the program and takes only what the benchmark
-made (``weights.py``).
+The network itself is the architecture module's ``forward`` (``archs/``),
+built from the operations here: direct convolutions at ``highest`` matmul
+precision (on a TPU a float32 matmul otherwise runs in one bf16 pass) and
+batch norm from running statistics. No Winograd transform, no
+quantisation, no kernels, no batching logic. It imports nothing of the
+program and takes only what the benchmark made (``weights.py``).
 
 ``bits=4`` gives the control: the same network with the inputs and weights
 of every stride-1 3x3 convolution, the layers the program serves in int8,
@@ -17,53 +17,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.weights import blocks
 
-
-def _fq(x, bits: int, axes):
+def fq(x, bits: int, axes):
     """Symmetric fake quantisation, one scale per slice kept by ``axes``."""
     qm = 2 ** (bits - 1) - 1
     s = jnp.maximum(jnp.max(jnp.abs(x), axis=axes, keepdims=True), 1e-30)
     return jnp.clip(jnp.round(x / s * qm), -qm, qm) * s / qm
 
 
-def _conv(x, w, stride: int, bits):
+def conv(x, w, stride: int, bits):
+    """A direct NHWC convolution; with ``bits``, a stride-1 3x3 one on
+    fake-quantised inputs and weights."""
     if bits is not None and stride == 1 and w.shape[0] == 3:
-        x = _fq(x, bits, (1, 2, 3))          # per image
-        w = _fq(w, bits, (0, 1, 2))          # per output channel
+        x = fq(x, bits, (1, 2, 3))          # per image
+        w = fq(w, bits, (0, 1, 2))          # per output channel
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         precision=jax.lax.Precision.HIGHEST)
 
 
-def _bn(x, p, s):
+def bn(x, p, s):
     return (x - s["mean"]) * jax.lax.rsqrt(s["var"] + 1e-5) * p["scale"] \
         + p["bias"]
 
 
-def forward(cfg: dict, params, state, x, bits=None):
-    """images ``(B, H, W, C)`` → logits ``(B, classes)``."""
-    x = jax.nn.relu(_bn(_conv(x, params["stem"], 1, bits),
-                        params["bn_stem"], state["bn_stem"]))
-    for name, _, _, stride in blocks(cfg):
-        p, s = params["blocks"][name], state["blocks"][name]
-        h = jax.nn.relu(_bn(_conv(x, p["conv1"], stride, bits),
-                            p["bn1"], s["bn1"]))
-        h = _bn(_conv(h, p["conv2"], 1, bits), p["bn2"], s["bn2"])
-        if "proj" in p:
-            x = _bn(_conv(x, p["proj"], stride, bits), p["bn_proj"],
-                    s["bn_proj"])
-        x = jax.nn.relu(h + x)
-    x = jnp.mean(x, axis=(1, 2))
-    return jnp.dot(x, params["head"], precision=jax.lax.Precision.HIGHEST) \
-        + params["head_b"]
-
-
-def logits(cfg: dict, params, state, images: np.ndarray, bits=None,
+def logits(arch, cfg: dict, params, state, images: np.ndarray, bits=None,
            block: int = 256) -> np.ndarray:
     """The reference over ``images`` in blocks of rows, on the host."""
-    fn = jax.jit(lambda x: forward(cfg, params, state, x, bits))
+    fn = jax.jit(lambda x: arch.forward(cfg, params, state, x, bits))
     out = []
     for i in range(0, len(images), block):
         x = images[i:i + block]

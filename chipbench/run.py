@@ -54,13 +54,14 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     manifest = harness.load_manifest(ROOT / "BENCHMARK.json")
-    cell, cfg, mix = harness.resolve(manifest, args.workload)
+    cell, arch, cfg, mix = harness.resolve(manifest, args.workload)
     devices = require_chips(cell["chips"])
     harness.log(f"compile cache: {enable_compile_cache()}")
     harness.log(f"device: {devices[0].platform} {devices[0].device_kind} "
                 f"x{len(devices)}")
-    result = harness.run(manifest, cell, cfg, mix, args.seed, args.seconds,
-                         bool(args.trace), T_START, Served, devices)
+    result = harness.run(manifest, cell, arch, cfg, mix, args.seed,
+                         args.seconds, bool(args.trace), T_START, Served,
+                         devices)
     print(json.dumps(result))
     return 0
 

@@ -136,9 +136,9 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     manifest = harness.load_manifest(ROOT / "BENCHMARK.json")
-    cell, cfg, mix = harness.resolve(manifest, args.workload)
+    cell, arch, cfg, mix = harness.resolve(manifest, args.workload)
     harness.log(f"compile cache: {enable_compile_cache()}")
-    server = harness.Server(cfg, mix, args.seed, Served)
+    server = harness.Server(arch, cfg, mix, args.seed, Served)
     setup_s = time.perf_counter() - T_START
     warm = server.system.compiles()
     prof = harness.Profiler(lead=min(2.0, args.seconds / 4),

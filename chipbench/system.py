@@ -1,10 +1,11 @@
 """The system under test, built the way the serving launcher builds it.
 
-engine → prepare (pack) → calibrate → checkpoint → restore through
-``repro.launch.serve.make_served_engine`` → ``resnet.serving_forward``,
-the jitted forward that ``repro.serving.ServingLoop`` drives. Weights and
-calibration images come from the benchmark (``weights.py``), not from the
-program's fixed keys.
+The architecture module's ``program(cfg)`` gives the program's model module
+and its config; then engine → prepare (pack) → calibrate → checkpoint →
+restore through ``repro.launch.serve.make_served_engine`` → the model's
+``serving_forward``, the jitted forward that ``repro.serving.ServingLoop``
+drives. Weights and calibration images come from the benchmark
+(``weights.py``), not from the program's fixed keys.
 """
 from __future__ import annotations
 
@@ -14,47 +15,26 @@ import jax
 from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.checkpoint import save
-from repro.core.quantization import QuantConfig
-from repro.core.winograd import WinogradSpec
 from repro.launch import serve
-from repro.models import resnet as RN
-
-
-def resnet_config(cfg: dict) -> RN.ResNetConfig:
-    """The program's config for a benchmark configuration file. The
-    file's widths are checked against what the program builds."""
-    wino, bits = cfg["winograd"], cfg["bits"]
-    rc = RN.ResNetConfig(
-        width_mult=cfg["widths"][0] / 64,
-        wino=WinogradSpec(m=wino["m"], r=wino["r"], base=wino["base"],
-                          quant=QuantConfig(act_bits=bits["act"],
-                                            weight_bits=bits["weight"],
-                                            trans_bits=bits["transform"],
-                                            hadamard_bits=bits["hadamard"])),
-        num_classes=cfg["num_classes"])
-    if list(rc.widths) != list(cfg["widths"]):
-        raise ValueError(f"the program builds widths {rc.widths}, the "
-                         f"configuration states {cfg['widths']}")
-    return rc
 
 
 class Served:
     """The served model: ``forward`` is what the loop calls, ``jitted`` the
     program's own jitted forward (its compile count is read there)."""
 
-    def __init__(self, cfg: dict, params, state, calibration, ckpt_dir: str,
-                 log=print):
+    def __init__(self, arch, cfg: dict, params, state, calibration,
+                 ckpt_dir: str, log=print):
         self.split = {}
-        rc = resnet_config(cfg)
+        model, rc = arch.program(cfg)
         t0 = time.perf_counter()
-        engine = RN.make_engine(rc, backend="winograd_int8")
-        packed = engine.prepare(RN.conv_layers(params, rc))
+        engine = model.make_engine(rc, backend="winograd_int8")
+        packed = engine.prepare(model.conv_layers(params, rc))
         jax.block_until_ready([p.u_q for p in engine.packed.values()])
         self._lap("pack", t0)
         t0 = time.perf_counter()
         with engine.calibration():
             for batch in calibration:
-                jax.block_until_ready(RN.forward(
+                jax.block_until_ready(model.forward(
                     params, state, batch, rc, training=False,
                     engine=engine)[0])
         self._lap("calibrate", t0)
@@ -65,7 +45,7 @@ class Served:
                                                engine.state_template())
         self._lap("checkpoint_restore", t0)
         self.int8_layers = len(packed)
-        self.jitted = RN.serving_forward(params, state, rc, self.engine)
+        self.jitted = model.serving_forward(params, state, rc, self.engine)
         self.engine.serve_fn = self.jitted
         log(f"set-up: {self.int8_layers} int8 Winograd layers; "
             + ", ".join(f"{k} {v:.3f}s" for k, v in self.split.items()))

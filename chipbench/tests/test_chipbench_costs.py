@@ -10,13 +10,14 @@ import numpy as np
 import pytest
 
 from chipbench import costs, weights
-from chipbench.harness import ROOT
+from chipbench.harness import ROOT, architecture
 
 _HLO = {"int8": "s8", "int32": "s32", "float32": "f32"}
 
 
 def _config(name):
-    return json.loads((ROOT / "configs" / f"{name}.json").read_text())
+    cfg = json.loads((ROOT / "configs" / f"{name}.json").read_text())
+    return architecture(cfg["arch"]), cfg
 
 
 CONFIGS = sorted(p.stem for p in (ROOT / "configs").glob("*.json"))
@@ -44,9 +45,9 @@ def _layer_calls(m, layer, batch=256):
     from repro.core.quantization import QuantConfig
     from repro.core.winograd import WinogradSpec
     from repro.kernels.ops import winograd_conv2d_int8
-    cfg = _config(CONFIGS[0])       # the layer shapes: widths, image size
+    arch, cfg = _config(CONFIGS[0])  # the layer shapes: widths, image size
     _, ho, wo, k, cin, cout, _ = next(
-        c for c in costs.conv_layers(cfg) if c[0] == layer)
+        c for c in arch.conv_layers(cfg) if c[0] == layer)
     spec = WinogradSpec(m=m, r=3, base="legendre",
                         quant=QuantConfig(hadamard_bits=9))
     P = spec.n ** 2
@@ -107,8 +108,8 @@ def test_least_time_names_its_bound():
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_model_ops_and_parameters(name):
-    cfg = _config(name)
+    arch, cfg = _config(name)
     # 20 convolutions (14 stride-1 3x3, 3 stride-2 3x3, 3 1x1) and the head.
-    assert len(list(costs.conv_layers(cfg))) == 20
-    assert costs.model_ops_per_image(cfg) == 1_110_845_440
-    assert weights.param_count(cfg) == cfg["params"]
+    assert len(list(arch.conv_layers(cfg))) == 20
+    assert costs.model_ops_per_image(arch, cfg) == 1_110_845_440
+    assert weights.param_count(arch, cfg) == cfg["params"] == 11_173_962

@@ -34,10 +34,11 @@ def _manifest(config_file, traffic_name):
 
 
 def _run(config_file, traffic_name, factory, seconds=1.0,
-         traffic_dir=TESTDATA / "traffic"):
+         traffic_dir=TESTDATA / "traffic", arch_dir=ROOT / "archs"):
     man = _manifest(config_file, traffic_name)
-    cell, cfg, mix = harness.resolve(man, "w", traffic_dir=traffic_dir)
-    out = harness.run(man, cell, cfg, mix, 2**31 + 29, seconds, False,
+    cell, arch, cfg, mix = harness.resolve(man, "w", traffic_dir=traffic_dir,
+                                           arch_dir=arch_dir)
+    out = harness.run(man, cell, arch, cfg, mix, 2**31 + 29, seconds, False,
                       time.perf_counter(), factory, jax.devices())
     json.dumps(out, allow_nan=False)       # one valid JSON line
     assert list(out) == RESULT_KEYS
@@ -46,12 +47,15 @@ def _run(config_file, traffic_name, factory, seconds=1.0,
 
 TINY = "chipbench/testdata/configs/tiny-f43.json"
 TINY_SHARE = "chipbench/testdata/configs/tiny-f43-share.json"
+TINY_BOTTLENECK = "chipbench/testdata/configs/tiny-bottleneck.json"
 
 
-@pytest.mark.parametrize("config", [TINY, TINY_SHARE],
-                         ids=["rms-and-max", "share-over-level"])
-def test_rehearsal_last_line(config):
-    out = _run(config, "tiny-closed", reference_system())
+@pytest.mark.parametrize("config,arch_dir", [
+    (TINY, ROOT / "archs"), (TINY_SHARE, ROOT / "archs"),
+    (TINY_BOTTLENECK, TESTDATA / "archs")],
+    ids=["rms-and-max", "share-over-level", "bottleneck-stand-in"])
+def test_rehearsal_last_line(config, arch_dir):
+    out = _run(config, "tiny-closed", reference_system(), arch_dir=arch_dir)
     assert out["correct"] is True, out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 0
     assert set(out["metrics"]) == {"images_per_s", "setup_s"}

@@ -6,7 +6,7 @@ from types import SimpleNamespace as NS
 import pytest
 
 from chipbench import costs, trace
-from chipbench.harness import ROOT, reader
+from chipbench.harness import ROOT, architecture, reader
 
 MS = 1_000_000       # ns
 K1 = ('%input_transform.3 = s8[36,512,64]{2,1,0} custom-call(f32[36,512,64]'
@@ -117,8 +117,9 @@ def test_kernel_readers_on_the_chip_trace():
     cfg = json.loads((ROOT / "configs" / "resnet18-cifar-f23.json")
                      .read_text())
     cfg["winograd"]["m"] = 4            # the trace is of the F(4,3) program
-    ctx = NS(cfg=cfg, trace=r, peaks=costs.peaks("TPU v5 lite"), chips=1,
-             images_traced=16, log=lambda msg: None)
+    ctx = NS(arch=architecture(cfg["arch"]), cfg=cfg, trace=r,
+             peaks=costs.peaks("TPU v5 lite"), chips=1, images_traced=16,
+             log=lambda msg: None)
     roof = reader("wino_roofline")(ctx)
     assert 0 < roof < 100
     assert reader("wino_us_per_image")(ctx) == pytest.approx(
