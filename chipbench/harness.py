@@ -68,21 +68,14 @@ def _load(path: Path, kind: str):
     return mod
 
 
-def resolve(manifest: dict, workload: str, root: Path = ROOT.parent,
-            traffic_dir: Path = ROOT / "traffic",
-            arch_dir: Path = ROOT / "archs"):
-    """The cell, its architecture module (the configuration's ``arch``,
-    from ``arch_dir``), its configuration (the manifest's ``file``,
-    relative to ``root``) and its traffic mix (``<traffic_dir>/<name>.json``).
-    A configuration that names no architecture, one with no module, or a
-    key that neither the module nor ``COMMON_KEYS`` knows is refused here,
-    before anything is built."""
-    cells = {w["name"]: w for w in manifest["workloads"]}
-    if workload not in cells:
-        raise SystemExit(f"unknown workload {workload!r}; known: "
-                         f"{sorted(cells)}")
-    cell = cells[workload]
-    conf = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
+def configuration(manifest: dict, name: str, root: Path = ROOT.parent,
+                  arch_dir: Path = ROOT / "archs"):
+    """The manifest's configuration ``name``: its architecture module (the
+    file's ``arch``, from ``arch_dir``) and its file (the manifest's
+    ``file``, relative to ``root``). A file that names no architecture,
+    one with no module, or a key that neither the module nor
+    ``COMMON_KEYS`` knows is refused here, before anything is built."""
+    conf = {c["name"]: c for c in manifest["configs"]}[name]
     cfg = json.loads((root / conf["file"]).read_text())
     if "arch" not in cfg:
         raise ValueError(f"{conf['file']} names no 'arch'")
@@ -91,6 +84,21 @@ def resolve(manifest: dict, workload: str, root: Path = ROOT.parent,
     if unknown:
         raise ValueError(f"{conf['file']}: keys {sorted(unknown)} are not "
                          f"read by arch {cfg['arch']!r}")
+    return arch, cfg
+
+
+def resolve(manifest: dict, workload: str, root: Path = ROOT.parent,
+            traffic_dir: Path = ROOT / "traffic",
+            arch_dir: Path = ROOT / "archs"):
+    """The cell, its configuration's architecture module and file
+    (``configuration``) and its traffic mix
+    (``<traffic_dir>/<name>.json``)."""
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    if workload not in cells:
+        raise SystemExit(f"unknown workload {workload!r}; known: "
+                         f"{sorted(cells)}")
+    cell = cells[workload]
+    arch, cfg = configuration(manifest, cell["config"], root, arch_dir)
     mix = traffic.load(traffic_dir / f"{cell['traffic']}.json")
     return cell, arch, cfg, mix
 
@@ -105,6 +113,26 @@ def metrics_for(manifest: dict, cell: dict, trace_on: bool) -> list[dict]:
 def reader(name: str):
     """``metrics/<name>.py``'s ``read(ctx)``."""
     return _load(ROOT / "metrics" / f"{name}.py", "metric").read
+
+
+def context(red, names: dict, images: int, **given) -> SimpleNamespace:
+    """What a metric reader reads: ``trace``, the device trace reduced to
+    the traced window; ``scopes``, the program's scopes and host spans in
+    it (``names``: each HLO instruction's ``op_name``); ``images_traced``,
+    the images answered in it; and ``given`` (the cell, its architecture,
+    configuration and mix, ``chips``, ``peaks``, ``window``, ``log``)."""
+    return SimpleNamespace(trace=red, scopes=trace.scopes(red, names, images),
+                           images_traced=images, **given)
+
+
+def served_hlo(system, mix: dict, cfg: dict) -> str:
+    """The compiled HLO text of the served program at the mix's largest
+    bucket: the executable whose instructions the trace names (a mix with
+    several buckets runs others too, whose names this does not give)."""
+    import jax
+    x = jax.device_put(np.zeros((max(mix["buckets"]), *cfg["image_shape"]),
+                                np.float32))
+    return system.jitted.lower(x).compile().as_text()
 
 
 class Profiler(threading.Thread):
@@ -347,6 +375,11 @@ def run(manifest: dict, cell: dict, arch, cfg: dict, mix: dict, seed: int,
            "count": len(devices),
            "memory_peak_bytes": int((devices[0].memory_stats() or {}).get(
                "peak_bytes_in_use", 0))}
+    if trace_on:                # after the window, its compiles and peak
+        t0 = time.perf_counter()
+        names = trace.op_names(served_hlo(server.system, mix, cfg))
+        log(f"op_name fetch: {time.perf_counter() - t0:.3f}s, "
+            f"{len(names)} instructions")
     params, state, pool = server.params, server.state, server.pool
     del server                  # the program's state, before the reference
 
@@ -357,11 +390,13 @@ def run(manifest: dict, cell: dict, arch, cfg: dict, mix: dict, seed: int,
         dev["busy_s"], dev["window_s"] = red.busy_s, red.window_s
         out_extra["breakdown"] = red.breakdown()
         t_a, t_b = prof.span
-        ctx = SimpleNamespace(
-            arch=arch, cfg=cfg, mix=mix, cell=cell, chips=len(devices),
-            peaks=costs.peaks(devices[0].device_kind), trace=red, window=w,
-            log=log, images_traced=sum(b.n for b in w.batches
-                                       if t_a <= b.t_done <= t_b))
+        ctx = context(red, names, sum(b.n for b in w.batches
+                                      if t_a <= b.t_done <= t_b),
+                      arch=arch, cfg=cfg, mix=mix, cell=cell,
+                      chips=len(devices),
+                      peaks=costs.peaks(devices[0].device_kind), window=w,
+                      log=log)
+        log("scopes: " + json.dumps(ctx.scopes.summary()))
         values = {m["name"]: reader(m["name"])(ctx) for m in wanted}
     else:
         values = dict(end_to_end(w), setup_s=setup_s)

@@ -1,67 +1,44 @@
 """Architecture modules: what the existing configurations read, pinned to
 values recorded before the network moved into ``archs/`` (weights,
-images, reference and control logits), and the refusals of a
-configuration whose architecture is unknown or differs from what the
-program builds."""
-import hashlib
+images, reference and control logits, in ``testdata/pins/``, one file per
+configuration), and the refusals of a configuration whose architecture
+is unknown or differs from what the program builds."""
 import json
 
-import jax
 import numpy as np
 import pytest
 
 from chipbench import harness, reference, weights
 from chipbench.harness import ROOT
+from chipbench.tests.manifest_checks import (
+    MANIFEST, check_images_pinned, check_weights_pinned, pins)
 
-MANIFEST = harness.load_manifest(ROOT.parent / "BENCHMARK.json")
-PINS = json.loads((ROOT / "testdata" / "pins.json").read_text())
-SEED = PINS["seed"]
-
-
-def _digest(a) -> str:
-    a = np.ascontiguousarray(np.asarray(a, np.float32))
-    return hashlib.sha256(a.tobytes()).hexdigest()[:16]
+CONFIGS = [c["name"] for c in MANIFEST["configs"]]
 
 
-def _cell(name):
-    cell, arch, cfg, mix = harness.resolve(MANIFEST, name)
-    return arch, cfg, mix, PINS["configs"][cell["config"]]
+@pytest.mark.parametrize("config", CONFIGS)
+def test_weights_pinned(config):
+    check_weights_pinned(MANIFEST, config)
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_weights_pinned(cell):
-    """Every leaf of ``(params, state)``, and so the order in which the key
-    is split over the flattened shape tree."""
-    arch, cfg, _, pins = _cell(cell)
-    params, state = weights.make_weights(arch, cfg, SEED)
-    flat = jax.tree_util.tree_flatten_with_path(
-        {"params": params, "state": state})[0]
-    got = {jax.tree_util.keystr(k, simple=True, separator="/"): _digest(v)
-           for k, v in flat}
-    assert got == pins["weights"]
-
-
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_images_pinned(cell):
-    """The cell's request pool and calibration batches."""
-    _, cfg, mix, pins = _cell(cell)
-    assert _digest(weights.images(cfg, SEED, mix["pool"])) == pins["pool"]
-    assert [_digest(x) for x in weights.calibration_images(cfg, SEED)] \
-        == pins["calibration"]
+@pytest.mark.parametrize("config", CONFIGS)
+def test_images_pinned(config):
+    check_images_pinned(MANIFEST, config)
 
 
 @pytest.mark.parametrize("bits,key", [(None, "reference"), (4, "control")])
 def test_reference_and_control_logits_pinned(bits, key):
+    pin = pins("tiny-f43")
     man = {"configs": [{"name": "c",
                         "file": "chipbench/testdata/configs/tiny-f43.json"}],
            "workloads": [{"name": "w", "config": "c",
                           "traffic": "tiny-closed", "chips": 1}]}
     _, arch, cfg, _ = harness.resolve(
         man, "w", traffic_dir=ROOT / "testdata" / "traffic")
-    params, state = weights.make_weights(arch, cfg, SEED)
-    x = weights.images(cfg, SEED, PINS["tiny-f43"]["images"])
+    params, state = weights.make_weights(arch, cfg, pin["seed"])
+    x = weights.images(cfg, pin["seed"], pin["images"])
     got = reference.logits(arch, cfg, params, state, x, bits=bits)
-    np.testing.assert_array_equal(got, np.array(PINS["tiny-f43"][key]))
+    np.testing.assert_array_equal(got, np.array(pin[key]))
 
 
 def _config_file(tmp_path, **change):
@@ -77,8 +54,8 @@ def _config_file(tmp_path, **change):
 
 
 @pytest.mark.parametrize("change,match", [
-    ({"arch": "resnet-imagenet"}, "no architecture module for arch "
-                                  "'resnet-imagenet'"),
+    ({"arch": "no-such-arch"}, "no architecture module for arch "
+                               "'no-such-arch'"),
     ({"arch": None}, "names no 'arch'"),
     ({"max_pool": True}, r"keys \['max_pool'\] are not read by arch "
                          "'resnet-cifar'")],

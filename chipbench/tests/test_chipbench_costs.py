@@ -9,8 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import costs, weights
+from chipbench import costs
 from chipbench.harness import ROOT, architecture
+from chipbench.tests.manifest_checks import MANIFEST, check_counts_pinned
 
 _HLO = {"int8": "s8", "int32": "s32", "float32": "f32"}
 
@@ -20,7 +21,7 @@ def _config(name):
     return architecture(cfg["arch"]), cfg
 
 
-CONFIGS = sorted(p.stem for p in (ROOT / "configs").glob("*.json"))
+CONFIGS = [c["name"] for c in MANIFEST["configs"]]
 
 
 def _pallas_calls(jaxpr):
@@ -45,7 +46,7 @@ def _layer_calls(m, layer, batch=256):
     from repro.core.quantization import QuantConfig
     from repro.core.winograd import WinogradSpec
     from repro.kernels.ops import winograd_conv2d_int8
-    arch, cfg = _config(CONFIGS[0])  # the layer shapes: widths, image size
+    arch, cfg = _config("resnet18-cifar-f23")  # the layer shapes
     _, ho, wo, k, cin, cout, _ = next(
         c for c in arch.conv_layers(cfg) if c[0] == layer)
     spec = WinogradSpec(m=m, r=3, base="legendre",
@@ -108,8 +109,8 @@ def test_least_time_names_its_bound():
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_model_ops_and_parameters(name):
-    arch, cfg = _config(name)
-    # 20 convolutions (14 stride-1 3x3, 3 stride-2 3x3, 3 1x1) and the head.
-    assert len(list(arch.conv_layers(cfg))) == 20
-    assert costs.model_ops_per_image(arch, cfg) == 1_110_845_440
-    assert weights.param_count(arch, cfg) == cfg["params"] == 11_173_962
+    """Against each configuration's own pin file: ResNet-18's pin 20
+    convolutions (14 stride-1 3x3, 3 stride-2 3x3, 3 1x1) and 1,110,845,440
+    operations per image with the head; its file states 11,173,962
+    parameters."""
+    check_counts_pinned(MANIFEST, name)

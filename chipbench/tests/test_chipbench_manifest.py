@@ -1,7 +1,7 @@
 """BENCHMARK.json against the format of its keys, every cell resolved to its
 files, and a new configuration, mix and architecture loaded from new files
 alone."""
-import json
+import copy
 import re
 
 import pytest
@@ -10,67 +10,85 @@ import numpy as np
 
 from chipbench import costs, harness, reference, traffic, weights
 from chipbench.harness import ROOT
+from chipbench.tests.manifest_checks import (
+    ARCHS, MANIFEST, check_cell, check_counts_pinned, check_images_pinned,
+    check_metric_entries, check_program, check_top_level,
+    check_weights_pinned)
 
-MANIFEST = harness.load_manifest(ROOT.parent / "BENCHMARK.json")
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 
 
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-
-
 def test_top_level_keys():
-    assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
-    assert MANIFEST["paths"] == ["chipbench"]
-    assert MANIFEST["command"] == ["python3", "chipbench/run.py"]
-    assert 1 <= MANIFEST["run_seconds"] <= 51
-    for entry in MANIFEST["configs"] + MANIFEST["workloads"]:
-        assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
-    for c in MANIFEST["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and c["file"].startswith("chipbench/")
+    check_top_level(MANIFEST)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_to_its_files(cell):
-    w, arch, cfg, mix = harness.resolve(MANIFEST, cell)
-    assert w["chips"] == 1
-    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-    arch.program(cfg)                       # the program builds the network
-    assert weights.param_count(arch, cfg) == cfg["params"]
-    assert set(cfg["limits"]) <= {"logit_err_rms", "logit_err_max",
-                                  "share_over_err_level"}
-    e2e = harness.metrics_for(MANIFEST, w, trace_on=False)
-    layer = harness.metrics_for(MANIFEST, w, trace_on=True)
-    names = {m["name"] for m in e2e}
-    assert "setup_s" in names and len(names) >= 2
-    assert layer
-    for m in layer:
-        assert m["moves"] in names          # the cell reports what it moves
-        assert callable(harness.reader(m["name"]))
+    check_cell(MANIFEST, cell)
+    check_program(MANIFEST, cell)
 
 
 def test_metric_entries():
-    cells = set(CELLS)
-    for m in MANIFEST["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.25
-    for m in MANIFEST["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert (ROOT / "metrics" / f"{m['name']}.py").is_file()
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        assert NAME.match(m["name"]) and m["better"] in ("lower", "higher")
-        assert UNIT.match(m["unit"])
-        assert set(m.get("workloads", cells)) <= cells
-    confs = {c["name"] for c in MANIFEST["configs"]}
-    assert confs == {w["config"] for w in MANIFEST["workloads"]}
+    check_metric_entries(MANIFEST)
 
 
 TESTDATA = ROOT / "testdata"
+
+#: Configurations whose configuration and pin files exist only under
+#: testdata/: a copy of ``resnet18-cifar-f43`` under a name of its own,
+#: and the reference-only bottleneck stand-in, whose architecture module
+#: lies there too (its stem, max-pool, blocks and head are not ResNet-18's,
+#: nor are its counts).
+NEW = {"resnet18-cifar-f43-copy": ARCHS,
+       "tiny-bottleneck": TESTDATA / "archs"}
+
+
+def _with_new_configuration(name):
+    man = copy.deepcopy(MANIFEST)
+    man["configs"].append({
+        "name": name, "source": "https://arxiv.org/abs/2004.11077",
+        "file": f"chipbench/testdata/configs/{name}.json", "reduced": [],
+        "why": "a configuration that only the tests know"})
+    man["workloads"].append({
+        "name": "new-offline-b256", "config": name, "traffic": "offline-b256",
+        "chips": 1, "why": "the offline mix on the new configuration"})
+    return man
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_new_configuration_by_new_files_alone(name):
+    """One config entry and one workload entry more, and files that are
+    new: every check of the manifest, of the new cell and of the new
+    configuration's pins and counts passes, with no other entry or file
+    changed. The stand-in has no program."""
+    man, arch_dir = _with_new_configuration(name), NEW[name]
+    check_top_level(man)
+    check_metric_entries(man)
+    check_cell(man, "new-offline-b256", arch_dir)
+    e2e = harness.metrics_for(man, man["workloads"][-1], trace_on=False)
+    layer = harness.metrics_for(man, man["workloads"][-1], trace_on=True)
+    assert {m["name"] for m in e2e} == {m["name"]
+                                        for m in MANIFEST["end_to_end"]}
+    assert {m["name"] for m in layer} == {m["name"]
+                                          for m in MANIFEST["per_layer"]}
+    check_weights_pinned(man, name, arch_dir=arch_dir)
+    check_images_pinned(man, name, arch_dir=arch_dir)
+    check_counts_pinned(man, name, arch_dir=arch_dir)
+    if arch_dir == ARCHS:
+        check_program(man, "new-offline-b256")
+    else:
+        with pytest.raises(NotImplementedError):
+            check_program(man, "new-offline-b256", arch_dir)
+
+
+def test_configuration_without_pins_names_the_file(tmp_path):
+    name = "resnet18-cifar-f43-copy"
+    man = _with_new_configuration(name)
+    for check in (check_weights_pinned, check_images_pinned,
+                  check_counts_pinned):
+        with pytest.raises(FileNotFoundError,
+                           match=re.escape(f"add {tmp_path / name}.json")):
+            check(man, name, tmp_path)
 
 
 @pytest.mark.parametrize("name,arch_dir,counts", [

@@ -1,12 +1,13 @@
-"""``chipbench/scopes.py`` on a made-up trace: device time by the program's
-scopes, the dispatcher's host time, idle time inside a collection, and idle
-gaps labelled by the program's spans."""
+"""The program's scopes and spans in a made-up trace, as the harness gives
+them to the metric readers (``trace.scopes``, ``harness.context``): device
+time by the program's scopes, the dispatcher's host time, idle time inside
+a collection, and idle gaps labelled by the program's spans."""
 from types import SimpleNamespace as NS
 
 import pytest
 
-from chipbench import scopes, trace
-from repro.telemetry import scope_of
+from chipbench import trace
+from chipbench.harness import context, reader
 
 MS = 1_000_000       # ns
 KERNEL = ('%input_transform.3 = s8[36,512,64]{2,1,0} custom-call(f32[36,512,'
@@ -45,7 +46,7 @@ def _profile():
 
 
 def test_program_spans_are_the_repro_host_spans():
-    spans = scopes.program_spans(_profile())
+    spans = trace.reduce(_profile(), 1).program_spans
     assert [n for n, _, _ in spans] == [
         "repro.serving.pad", "repro.serving.block",
         "repro.serving.coalesce", "repro.gc", "repro.setup.warmup"]
@@ -53,15 +54,17 @@ def test_program_spans_are_the_repro_host_spans():
 
 
 def test_reduce_window():
-    prof = _profile()
-    red = trace.reduce(prof, 1)
-    out = scopes.reduce_window(red, scopes.program_spans(prof), OP_NAMES,
-                               scope_of, images=10)
-    assert out["extract_us_per_image"] == pytest.approx(300.0)
-    assert out["direct_us_per_image"] == pytest.approx(200.0)
+    ctx = context(trace.reduce(_profile(), 1), OP_NAMES, 10)
+    assert reader("extract_us_per_image")(ctx) == pytest.approx(300.0)
+    assert reader("direct_us_per_image")(ctx) == pytest.approx(200.0)
     # pad 1 ms + coalesce 3.5 ms; block is waiting, not host work
-    assert out["host_us_per_image"] == pytest.approx(450.0)
-    assert out["idle_in_gc"] == pytest.approx(20.0)        # 2 of 10 ms
+    assert reader("host_us_per_image")(ctx) == pytest.approx(450.0)
+    assert reader("idle_in_gc.offline")(ctx) == pytest.approx(20.0)  # 2/10
+    # a layer's scope as well as a stage's
+    assert ctx.scopes.scope_s("s0b0.conv1") == pytest.approx(0.003)
+    assert ctx.scopes.scope_s("stem") == pytest.approx(0.002)
+    assert ctx.scopes.scope_s("max_pool") is None
+    out = ctx.scopes.summary()
     assert out["glue_named_share"] == pytest.approx(5 / 6)  # copy-done
     assert out["device_scopes"][0] == ["s0b0.conv1", "wino_extract",
                                        pytest.approx(0.003)]
@@ -73,6 +76,63 @@ def test_label_by_own_time():
     """A collection inside a longer stage names the gap while it covers
     most of the gap, the stage when the stage's own time does."""
     spans = [("repro.serving.pad", 0.0, 10.0), ("repro.gc", 2.0, 6.0)]
-    assert scopes.label(spans, 1.0, 7.0) == "repro.gc"      # 4 of 6
-    assert scopes.label(spans, 0.0, 10.0) == "repro.serving.pad"  # 6 of 10
-    assert scopes.label([], 0.0, 1.0) == "no repro span"
+    assert trace.program_label(spans, 1.0, 7.0) == "repro.gc"      # 4 of 6
+    assert trace.program_label(spans, 0.0, 10.0) == \
+        "repro.serving.pad"                                         # 6 of 10
+    assert trace.program_label([], 0.0, 1.0) == "no repro span"
+
+
+def test_readers_silent_without_what_they_read():
+    """A trace with no program spans and no scoped instruction, or a window
+    that answered no image: the readers return nothing, never 0. A window
+    with the program's spans but no collection in it is idle in none: 0."""
+    prof = _profile()
+    prof.planes[0].lines[0].events = prof.planes[0].lines[0].events[:1]
+    names = ("extract_us_per_image", "direct_us_per_image",
+             "host_us_per_image", "idle_in_gc.offline")
+    ctx = context(trace.reduce(prof, 1), {}, 10)
+    assert [reader(n)(ctx) for n in names] == [None] * 4
+    ctx = context(trace.reduce(_profile(), 1), OP_NAMES, 0)
+    assert [reader(n)(ctx) for n in names[:3]] == [None] * 3
+    prof = _profile()
+    prof.planes[0].lines[0].events = [
+        e for e in prof.planes[0].lines[0].events if e.name != "repro.gc"]
+    ctx = context(trace.reduce(prof, 1), OP_NAMES, 10)
+    assert reader("idle_in_gc.offline")(ctx) == 0.0
+
+
+HLO = '''HloModule jit_serve_resnet, entry_computation_layout={(f32[8]{0})->f32[8]}
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %pad.3 = f32[8]{0} pad(f32[8]{0} %param_0.1, f32[] %c), padding=0_0, metadata={op_name="jit(serve_resnet)/s0b0.conv1/wino_extract/jit(_extract)/pad" source_file="ops.py" source_line=12}
+}
+
+ENTRY %main.9 (Arg_0.1: f32[8]) -> f32[8] {
+  %Arg_0.1 = f32[8]{0} parameter(0)
+  %input_transform.3 = s8[8]{0} custom-call(f32[8]{0} %Arg_0.1), custom_call_target="tpu_custom_call", backend_config={"custom_call_config": {"body": "x}y"}}, metadata={op_name="jit(serve_resnet)/stem/wino_input_transform/pallas_call"}
+  ROOT %fusion.1 = f32[8]{0} fusion(f32[8]{0} %Arg_0.1), kind=kLoop, calls=%fused_computation.1
+}
+'''
+
+
+def test_op_names_and_scopes_from_hlo_text():
+    """Every instruction of every computation by name, with its
+    ``op_name`` or "" where it has none, and the ``(layer, stage)`` of
+    each."""
+    names = trace.op_names(HLO)
+    assert names == {
+        "param_0.1": "", "Arg_0.1": "", "fusion.1": "",
+        "pad.3": OP_NAMES["fusion.1"].replace("fusion.1", "pad.3"),
+        "input_transform.3": "jit(serve_resnet)/stem/wino_input_transform/"
+                             "pallas_call"}
+    assert trace.scope_of(names["pad.3"]) == ("s0b0.conv1", "wino_extract")
+    assert trace.scope_of(names["input_transform.3"]) == (
+        "stem", "wino_input_transform")
+    assert trace.scope_of(OP_NAMES["fusion.2"]) == ("s1b0.conv1", "direct")
+    assert trace.scope_of("jit(serve_resnet)/head/dot_general") == (
+        None, "head")
+    assert trace.scope_of("jit(serve_resnet)/add") == (None, None)
+    # any scope the program opens, with no list of them
+    assert trace.scope_path("jit(serve_resnet)/stem/max_pool/jit(_pool)/"
+                            "reduce_window") == ("stem", "max_pool")
